@@ -17,6 +17,7 @@ torch.utils.data.DataLoader worker *processes*
   modes produce identical batches in identical order (tested).
 """
 
+import itertools
 import queue
 import threading
 
@@ -504,9 +505,9 @@ class DataLoader:
         skip = [0] * n      # replayed batches to discard after a restart
         obs_on = obs.enabled()
         if obs_on:
-            import time as _time
             from ..observability import attribution
-            stage, pc = attribution.stage_counter(), _time.perf_counter
+            stage = attribution.stage_counter()
+            n_out = 0  # batches yielded this epoch: the spans' batch id
         try:
             while live:
                 for w in list(live):
@@ -563,9 +564,10 @@ class DataLoader:
                         # IPC stage: the cross-process payload decode the
                         # thread mode never pays (queue WAIT is already
                         # covered by the batch_wait boundary upstream).
-                        t0 = pc()
-                        decoded = qserde.decode(payload)
-                        stage.inc(pc() - t0, stage="ipc")
+                        with obs.span("loader.ipc", batch=n_out) as span:
+                            decoded = qserde.decode(payload)
+                        stage.inc(span.seconds, stage="ipc")
+                        n_out += 1
                         yield decoded
                     else:
                         yield qserde.decode(payload)
@@ -594,27 +596,35 @@ class DataLoader:
 
     def _iter_instrumented(self, inner):
         """Top-level loader span + per-batch latency/padding accounting.
-        Wall time between consumer next() calls is the batch latency the
-        training loop actually experiences (prefetch included). The same
-        two timestamps also feed the attribution boundary pair:
-        ``batch_wait`` (consumer blocked in next()) and ``step_gap``
-        (consumer away between batches) partition the epoch wall exactly
-        — the input-share those yield is what the bound verdict reads."""
-        import time
+        Each wait in the inner ``next()`` is a ``loader.batch_wait`` span;
+        its duration is the batch latency the training loop actually
+        experiences (prefetch included). The same span boundaries also
+        feed the attribution boundary pair: ``batch_wait`` (consumer
+        blocked in next()) and ``step_gap`` (consumer away between
+        batches) partition the epoch wall exactly — the input-share those
+        yield is what the bound verdict reads."""
         from ..observability import attribution
         watcher = _EpochObserver()
         stage = attribution.stage_counter()
+        end = object()
         try:
-            with obs.span("loader.epoch", mode=self._worker_mode,
+            # Open across the yields below, so off the profiler timeline.
+            with obs.span("loader.epoch", annotate=False,
+                          mode=self._worker_mode,
                           batch_size=self.batch_size):
-                t0 = time.perf_counter()
-                for batch in inner:
-                    t_ready = time.perf_counter()
-                    watcher.batch(batch, t_ready - t0)
-                    stage.inc(t_ready - t0, stage="batch_wait")
+                n, back = 0, None
+                while True:
+                    with obs.span("loader.batch_wait", batch=n) as wait:
+                        batch = next(inner, end)
+                    stage.inc(wait.seconds, stage="batch_wait")
+                    if back is not None:
+                        stage.inc(wait.start - back, stage="step_gap")
+                    if batch is end:
+                        return
+                    watcher.batch(batch, wait.seconds)
+                    n += 1
                     yield batch
-                    t0 = time.perf_counter()
-                    stage.inc(t0 - t_ready, stage="step_gap")
+                    back = wait.start + wait.seconds
         finally:
             # Abandoned epochs still summarize what they served.
             watcher.finish()
@@ -687,23 +697,18 @@ class _DevicePrefetcher:
             return False
 
         obs_on = obs.enabled()
-        if obs_on:
-            from ..observability import attribution
-            reg = obs.registry()
-            batches = reg.counter("loader_prefetch_batches_total")
-            wait = reg.histogram("loader_prefetch_wait_seconds")
-            stage = attribution.stage_counter()
         device_put = self._device_put
         if obs_on:
-            import time as _time
+            from ..observability import attribution
+            stage = attribution.stage_counter()
+            batch_ids = itertools.count()
 
-            def device_put(b, _d=self._device_put, _s=stage,
-                           _pc=_time.perf_counter):
+            def device_put(b, _d=self._device_put):
                 # h2d stage: the dispatch cost of the transfer (the
                 # transfer itself is asynchronous — overlap is the point).
-                t0 = _pc()
-                out = _d(b)
-                _s.inc(_pc() - t0, stage="h2d")
+                with obs.span("loader.h2d", batch=next(batch_ids)) as span:
+                    out = _d(b)
+                stage.inc(span.seconds, stage="h2d")
                 return out
 
         def produce():
@@ -721,25 +726,24 @@ class _DevicePrefetcher:
         t = threading.Thread(target=produce, daemon=True)
         t.start()
         try:
-            import time
-            t_yield = None
+            n, back = 0, None
             while True:
-                t0 = time.perf_counter() if obs_on else 0.0
-                if obs_on and t_yield is not None:
-                    # The consumer was away running its step: that gap is
-                    # the compute side of the outermost boundary pair.
-                    stage.inc(t0 - t_yield, stage="prefetch_gap")
-                kind, payload = q.get()
+                if not obs_on:
+                    kind, payload = q.get()
+                else:
+                    with obs.span("loader.prefetch_wait", batch=n) as wait:
+                        kind, payload = q.get()
+                    stage.inc(wait.seconds, stage="prefetch_wait")
+                    if back is not None:
+                        # The consumer was away running its step: that gap
+                        # is the compute side of the outermost boundary.
+                        stage.inc(wait.start - back, stage="prefetch_gap")
+                    back = wait.start + wait.seconds
+                    n += 1
                 if kind == "error":
                     raise payload
                 if kind == "end":
                     return
-                if obs_on:
-                    dt = time.perf_counter() - t0
-                    batches.inc()
-                    wait.observe(dt)
-                    stage.inc(dt, stage="prefetch_wait")
-                    t_yield = time.perf_counter()
                 yield payload
         finally:
             stop.set()
@@ -757,10 +761,10 @@ def prefetch_to_device(loader, device_put=None, depth=2):
     globally-sharded batches on a device mesh (benchmarks/mock_train.py
     --with-model does). The wrapper is re-iterable — each ``iter()``
     advances the wrapped loader one epoch — and order-preserving, so the
-    determinism contract is untouched. Telemetry (when armed):
-    ``loader_prefetch_batches_total`` and ``loader_prefetch_wait_seconds``
-    (time the consumer actually blocked on the queue — near zero when the
-    pipeline keeps up)."""
+    determinism contract is untouched. Telemetry (when armed): the
+    ``loader.prefetch_wait`` span around the consumer's wait on the queue
+    (near zero when the pipeline keeps up) and the ``loader.h2d`` span
+    around each ``device_put``, each with its stage counter."""
     if device_put is None:
         import jax
         device_put = jax.device_put

@@ -9,6 +9,7 @@ all-gather around the sequence-sharded regions).
 """
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,12 @@ import flax.linen as nn
 from flax import struct
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import observability as obs
 from .bert import BertForPreTraining, axis_rules_for
+
+# Host dispatch of the train step, counted while telemetry is armed.
+DISPATCH_SECONDS = "train_step_dispatch_seconds_total"
+DISPATCHES = "train_step_dispatches_total"
 
 
 class TrainState(struct.PyTreeNode):
@@ -292,14 +298,18 @@ def _make_step_fn(model, batch_loss, ignore_index=-1, mlm_gather_ok=True):
                 rngs={"dropout": dropout_rng},
                 **kwargs,
             )
-            return batch_loss(outputs, batch)
+            with jax.named_scope("loss"):
+                return batch_loss(outputs, batch)
 
         (_, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        metrics = dict(metrics, grad_norm=optax.global_norm(grads))
+        # Named scopes give the device trace's ops an owner; the forward
+        # and backward passes carry flax's module scopes already.
+        with jax.named_scope("optimizer"):
+            metrics = dict(metrics, grad_norm=optax.global_norm(grads))
+            new_state = state.apply_gradients(grads)
         if extra:
             metrics = dict(metrics, **extra)
-        new_state = state.apply_gradients(grads)
         return new_state, metrics
 
     return step_fn
@@ -314,20 +324,36 @@ def make_sharded_train_step(mesh, config, model=None, ignore_index=-1,
     deterministic per (seed, step). ``batch_loss(outputs, batch)`` ->
     (loss, metrics) adapts non-BERT models (e.g. models.bart; bind its
     ignore_index yourself, e.g. functools.partial(bart_batch_loss,
-    ignore_index=...))."""
+    ignore_index=...)).
+
+    With telemetry armed, each call is a ``train_step`` span (a
+    ``StepTraceAnnotation`` numbered by this step's own dispatch count)
+    and adds its host dispatch time to ``train_step_dispatch_seconds_total``
+    and one to ``train_step_dispatches_total``."""
     model = model or BertForPreTraining(config)
     step_fn = _make_step_fn(model,
                             _resolve_batch_loss(batch_loss, ignore_index),
                             ignore_index, mlm_gather_ok=batch_loss is None)
 
     jitted = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
+    dispatches = itertools.count()
 
-    def wrapped(state, batch, seed=0):
+    def dispatch(state, batch, seed):
         # Both contexts must be live at trace time: axis_rules resolves the
         # logical constraints, set_mesh resolves bare PartitionSpecs.
         with jax.set_mesh(mesh), nn.logical_axis_rules(
                 axis_rules_for(mesh)):
             return jitted(state, batch, seed)
+
+    def wrapped(state, batch, seed=0):
+        if not obs.enabled():
+            return dispatch(state, batch, seed)
+        with obs.step_span("train_step", next(dispatches)) as span:
+            out = dispatch(state, batch, seed)
+        reg = obs.registry()
+        reg.counter(DISPATCH_SECONDS).inc(span.seconds)
+        reg.counter(DISPATCHES).inc()
+        return out
 
     def lower(state, batch, seed=0):
         """``jax.stages.Lowered`` of the step, for callers that inspect

@@ -49,7 +49,7 @@ from .registry import (
     registry,
     set_gauge,
 )
-from .tracing import event, flush, span, trace_path
+from .tracing import event, flush, span, step_span, trace_path
 
 __all__ = [
     "Counter",
@@ -75,6 +75,7 @@ __all__ = [
     "set_gauge",
     "span",
     "start_periodic_export",
+    "step_span",
     "stop_periodic_export",
     "summary",
     "trace_path",

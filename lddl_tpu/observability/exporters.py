@@ -22,6 +22,7 @@ rather than the run.
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -32,6 +33,10 @@ _EXPORT_INTERVAL_ENV = "LDDL_TPU_METRICS_INTERVAL_S"
 
 _thread_lock = threading.Lock()
 _exporter = {"thread": None, "stop": None}
+
+# jax's event for one XLA compile (a persistent-cache hit emits none).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener = []
 
 
 def _file_tag():
@@ -50,7 +55,37 @@ def configure(dir=None, rank=None, periodic=False):  # noqa: A002
         os.environ[ENV_RANK] = str(int(rank))
     if periodic:
         start_periodic_export()
+    if "jax" in sys.modules:
+        _count_compiles()
     return metrics_dir()
+
+
+def _on_compile(event, duration, **_):
+    if event != COMPILE_EVENT or metrics_dir() is None:
+        return
+    try:
+        reg = registry()
+        reg.counter("jax_compiles_total",
+                    help="XLA compiles made by this process").inc()
+        reg.counter("jax_compile_seconds_total",
+                    help="seconds spent in XLA compiles").inc(duration)
+        tracing.event("jax.compile", seconds=duration)
+    except Exception:  # noqa: BLE001 - telemetry must stay inert
+        pass
+
+
+def _count_compiles():
+    """Register, once per process, the jax.monitoring listener that counts
+    compiles where they happen (a recompile inside a training window shows
+    in the counters and as a ``jax.compile`` instant in the trace). It
+    records only while telemetry is armed. Called by ``configure`` in a
+    process that has already imported jax."""
+    with _thread_lock:
+        if _compile_listener:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listener.append(_on_compile)
 
 
 def disable():

@@ -8,6 +8,19 @@ JSON trace importer accepts newline-delimited events without the
 enclosing ``[]``). ``event(...)`` records an instant ("ph": "i") event
 for point occurrences (a retry, a fault injection, a worker restart).
 
+In a process that has imported jax, an armed span is also a
+``jax.profiler.TraceAnnotation`` for its duration: a profiler session
+running at the time records it in its host plane, on the device trace's
+clock, so program spans and device ops share one timeline. jax is never
+imported here (preprocess-only processes stay free of it), and without a
+session the annotation is one cheap check. A TraceMe must end on the
+thread it began on, so an annotated span is never held open across a
+``yield``; a span that is (a whole epoch) passes ``annotate=False`` and
+goes to the JSONL only. After exit a span exposes ``start`` (its
+``perf_counter`` at entry) and ``seconds`` (its duration): a stage
+counter at the same site is incremented from them, so the site reads the
+clock once and the counter and the span cannot disagree.
+
 Inertness contract (same as registry.py): disabled spans are a shared
 reusable null context manager (no allocation, one env lookup), enabled
 spans never raise into the caller and never touch any RNG. Events buffer
@@ -20,6 +33,7 @@ scatter/gather span tree span process boundaries.
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -50,26 +64,32 @@ class Span:
     """One timed section. Use via ``span(...)``; re-entrant use of a
     single instance is not supported (make a new span instead)."""
 
-    __slots__ = ("name", "args", "_t0", "_p0")
+    __slots__ = ("name", "args", "start", "seconds", "_t0", "_note")
 
-    def __init__(self, name, args):
+    def __init__(self, name, args, note=None):
         self.name = name
         self.args = args
+        self.start = 0.0
+        self.seconds = 0.0
         self._t0 = 0.0
-        self._p0 = 0.0
+        self._note = note
 
     def __enter__(self):
+        if self._note is not None:
+            self._note.__enter__()
         self._t0 = _now_us()
-        self._p0 = time.perf_counter()
+        self.start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = (time.perf_counter() - self._p0) * 1e6
+        self.seconds = time.perf_counter() - self.start
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         record = {
             "name": self.name,
             "ph": "X",
             "ts": self._t0,
-            "dur": dur,
+            "dur": self.seconds * 1e6,
             "pid": os.getpid(),
             "tid": threading.get_ident() & 0x7FFFFFFF,
         }
@@ -96,11 +116,35 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-def span(name, **args):
-    """Context manager timing one section; a shared no-op when disabled."""
+def _annotation(name, args, step=None):
+    """The profiler annotation of an armed span, or None where jax is not
+    imported. ``step`` makes it a ``StepTraceAnnotation``."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        if step is not None:
+            return jax.profiler.StepTraceAnnotation(name, step_num=step)
+        return jax.profiler.TraceAnnotation(name, **args)
+    except Exception:  # noqa: BLE001 - telemetry must stay inert
+        return None
+
+
+def span(name, *, annotate=True, **args):
+    """Context manager timing one section; a shared no-op when disabled.
+    ``annotate=False`` keeps it off the profiler's timeline, for a span
+    that stays open across a ``yield``."""
     if metrics_dir() is None:
         return _NULL_SPAN
-    return Span(name, args)
+    return Span(name, args, _annotation(name, args) if annotate else None)
+
+
+def step_span(name, step):
+    """``span(name, step=step)`` that also marks a training step for the
+    profiler (``StepTraceAnnotation(name, step_num=step)``)."""
+    if metrics_dir() is None:
+        return _NULL_SPAN
+    return Span(name, {"step": step}, _annotation(name, None, step))
 
 
 def event(name, **args):

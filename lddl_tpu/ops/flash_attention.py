@@ -242,6 +242,7 @@ def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None):
         spec, spec_mask, spec_row = _onekv_specs(nbh, l_pad, d, h)
         out, lse = pl.pallas_call(
             functools.partial(_onekv_fwd_kernel, scale=scale, nbh=nbh),
+            name="flash_fwd_onekv",
             grid=(b * h // nbh,),
             in_specs=[spec, spec, spec, spec_mask, spec_mask],
             out_specs=[spec, spec_row],
@@ -258,6 +259,7 @@ def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None):
                                n_kv=l_pad // tk, tk=tk)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b * h, l_pad // tq),
         in_specs=[
             pl.BlockSpec((1, tq, d), lambda bh, qi: (bh, qi, 0)),
@@ -306,6 +308,7 @@ def flash_attention_bwd(q, k, v, kv_mask, out, lse, ct, interpret=None,
         spec, spec_mask, spec_row = _onekv_specs(nbh, l_pad, d, h)
         dq, dk, dv = pl.pallas_call(
             functools.partial(_onekv_bwd_kernel, scale=scale, nbh=nbh),
+            name="flash_bwd_onekv",
             grid=(b * h // nbh,),
             in_specs=[spec, spec, spec, spec_mask, spec_mask, spec,
                       spec_row, spec_row],
@@ -322,6 +325,7 @@ def flash_attention_bwd(q, k, v, kv_mask, out, lse, ct, interpret=None,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale,
                           n_kv=l_pad // tk, tk=tk),
+        name="flash_bwd_dq",
         grid=(b * h, l_pad // tq),
         in_specs=[
             pl.BlockSpec((1, tq, d), lambda bh, qi: (bh, qi, 0)),   # q
@@ -343,6 +347,7 @@ def flash_attention_bwd(q, k, v, kv_mask, out, lse, ct, interpret=None,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale,
                           n_q=l_pad // tq, tq=tq),
+        name="flash_bwd_dkdv",
         grid=(b * h, l_pad // tk),
         in_specs=[
             pl.BlockSpec((1, l_pad, d), lambda bh, ki: (bh, 0, 0)),  # q

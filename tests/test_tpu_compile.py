@@ -66,4 +66,11 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, head_dim, seq_len,
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         qkv, qkv, qkv, mask).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # Each kernel carries its stable name into the compiled program.
+    names = (("flash_fwd_onekv", "flash_bwd_onekv")
+             if regime == "single-block"
+             else ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv"))
+    for name in names:
+        assert name in text, name
