@@ -14,10 +14,47 @@ decoding stays dense), and the ambient mesh has sp > 1.
 
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
 with_logical = nn.with_logical_constraint
+
+
+class Dropout(nn.Dropout):
+    """``flax.linen.Dropout`` whose keep-mask is drawn once per step.
+
+    The boolean mask passes through ``jax.lax.optimization_barrier``
+    before the select, so XLA materializes it: the backward reads the
+    stored mask instead of re-deriving it from the key in each of its
+    consumers (with threefry keys XLA otherwise hashes every mask three
+    times a step, on the VPU). The key, the ``bernoulli`` draw and the
+    arithmetic are flax's, so outputs and gradients are bit-identical to
+    ``nn.Dropout``; the cost is one byte per mask element kept until the
+    backward. The class keeps flax's name, so the sites are still
+    auto-named ``Dropout_<n>`` and every ``make_rng`` path is unchanged.
+    """
+
+    @nn.compact
+    def __call__(self, inputs, deterministic=None, rng=None):
+        deterministic = nn.merge_param("deterministic", self.deterministic,
+                                       deterministic)
+        if self.rate == 0.0 or deterministic:
+            return inputs
+        # Prevent gradient NaNs in the 1.0 edge case.
+        if self.rate == 1.0:
+            return jnp.zeros_like(inputs)
+        keep_prob = 1.0 - self.rate
+        if rng is None:
+            rng = self.make_rng(self.rng_collection)
+        broadcast_shape = list(inputs.shape)
+        for dim in self.broadcast_dims:
+            broadcast_shape[dim] = 1
+        mask = jax.random.bernoulli(rng, p=keep_prob, shape=broadcast_shape)
+        mask = jax.lax.optimization_barrier(mask)
+        mask = jnp.broadcast_to(mask, inputs.shape)
+        return jax.lax.select(mask, inputs / keep_prob,
+                              jnp.zeros_like(inputs))
 
 
 def resolve_auto_impl(seq_len, blockwise_ok, attention_dropout,
@@ -166,8 +203,8 @@ class MultiHeadAttention(nn.Module):
                 bias = bias + extra_bias
             probs = nn.softmax(scores + jnp.asarray(bias, self.dtype),
                                axis=-1)
-            probs = nn.Dropout(self.dropout)(probs,
-                                             deterministic=deterministic)
+            probs = Dropout(self.dropout)(probs,
+                                          deterministic=deterministic)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
         ctx = ctx.reshape(ctx.shape[0], ctx.shape[1],

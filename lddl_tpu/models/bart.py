@@ -21,6 +21,7 @@ from typing import Any
 import jax.numpy as jnp
 import flax.linen as nn
 
+from .attention import Dropout
 from .bert import (  # noqa: F401 (shared rules)
     LOGICAL_AXIS_RULES,
     _attention,
@@ -102,7 +103,7 @@ class Embeddings(nn.Module):
         x = with_logical(x + pos, ("batch", "seq", "act_embed"))
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="layer_norm")(x)
-        return nn.Dropout(cfg.hidden_dropout)(x, deterministic=deterministic)
+        return Dropout(cfg.hidden_dropout)(x, deterministic=deterministic)
 
 
 def causal_bias(length):
@@ -120,11 +121,11 @@ class EncoderLayer(nn.Module):
         cfg = self.cfg
         a = _attention(cfg, "self_attention")(x, x, padding_mask,
                                               deterministic)
-        a = nn.Dropout(cfg.hidden_dropout)(a, deterministic=deterministic)
+        a = Dropout(cfg.hidden_dropout)(a, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="self_norm")(x + a)
         h = _feed_forward(cfg)(x)
-        h = nn.Dropout(cfg.hidden_dropout)(h, deterministic=deterministic)
+        h = Dropout(cfg.hidden_dropout)(h, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="ffn_norm")(x + h)
         return with_logical(x, ("batch", "seq", "act_embed"))
@@ -140,16 +141,16 @@ class DecoderLayer(nn.Module):
         # bidirectional-only).
         a = _attention(cfg, "self_attention")(x, x, None, deterministic,
                                               extra_bias=self_bias)
-        a = nn.Dropout(cfg.hidden_dropout)(a, deterministic=deterministic)
+        a = Dropout(cfg.hidden_dropout)(a, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="self_norm")(x + a)
         c = _attention(cfg, "cross_attention")(x, enc, enc_padding_mask,
                                                deterministic)
-        c = nn.Dropout(cfg.hidden_dropout)(c, deterministic=deterministic)
+        c = Dropout(cfg.hidden_dropout)(c, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="cross_norm")(x + c)
         h = _feed_forward(cfg)(x)
-        h = nn.Dropout(cfg.hidden_dropout)(h, deterministic=deterministic)
+        h = Dropout(cfg.hidden_dropout)(h, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="ffn_norm")(x + h)
         return with_logical(x, ("batch", "seq", "act_embed"))

@@ -26,6 +26,8 @@ from typing import Any
 import jax.numpy as jnp
 import flax.linen as nn
 
+from .attention import Dropout, FeedForward, MultiHeadAttention
+
 # Logical-to-mesh sharding rules (see lddl_tpu.parallel.mesh for axes).
 #
 # "embed" names PARAM embed dims and maps to fsdp: with an fsdp mesh axis
@@ -200,14 +202,13 @@ class Embeddings(nn.Module):
         x = with_logical(x, ("batch", "seq", "act_embed"))
         x = nn.LayerNorm(epsilon=self.cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="layer_norm")(x)
-        x = nn.Dropout(cfg.hidden_dropout)(x, deterministic=deterministic)
+        x = Dropout(cfg.hidden_dropout)(x, deterministic=deterministic)
         return x
 
 
 def _attention(cfg, name):
     """The shared MultiHeadAttention configured from a model config; child
     params named query/key/value/output (stable checkpoint trees)."""
-    from .attention import MultiHeadAttention
     return MultiHeadAttention(
         hidden_size=cfg.hidden_size,
         num_heads=cfg.num_heads,
@@ -220,7 +221,6 @@ def _attention(cfg, name):
 
 def _feed_forward(cfg, name="ffn"):
     """The shared transformer MLP configured from a model config."""
-    from .attention import FeedForward
     return FeedForward(
         hidden_size=cfg.hidden_size,
         intermediate_size=cfg.intermediate_size,
@@ -238,12 +238,12 @@ class EncoderLayer(nn.Module):
         attn = _attention(cfg, "attention")(x, x, attention_mask,
                                             deterministic,
                                             segments=segments)
-        attn = nn.Dropout(cfg.hidden_dropout)(attn, deterministic=deterministic)
+        attn = Dropout(cfg.hidden_dropout)(attn, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="attention_norm")(x + attn)
 
         h = _feed_forward(cfg)(x)
-        h = nn.Dropout(cfg.hidden_dropout)(h, deterministic=deterministic)
+        h = Dropout(cfg.hidden_dropout)(h, deterministic=deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="ffn_norm")(x + h)
         return with_logical(x, ("batch", "seq", "act_embed"))

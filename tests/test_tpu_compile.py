@@ -1,14 +1,19 @@
-"""The Pallas flash kernels compile for a described (not attached) TPU v5e.
+"""The Pallas flash kernels and the train step compile for a described
+(not attached) TPU v5e.
 
 Interpret-mode tests cannot see what the chip's compiler refuses: slices
-off the tiling, more VMEM than a kernel may use. Each test here compiles
-flash forward+backward at real widths (bf16, 12 heads) for one chip of a
-v5e:2x2 topology and checks the program holds the kernel. The topology is
+off the tiling, more VMEM than a kernel may use. Each flash test compiles
+forward+backward at real widths (bf16, 12 heads) for one chip of a
+v5e:2x2 topology and checks the program holds the kernel; the dropout
+test reads how the chip's compiler fused a BERT-large step. The topology is
 described inside a fixture of this one file: only one process may load
 the TPU library, so describing it at import would fail on every other
 test worker and change what each collects.
 """
 
+import re
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
@@ -74,3 +79,99 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, head_dim, seq_len,
              else ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv"))
     for name in names:
         assert name in text, name
+
+
+def _top_level_fusions(hlo_text):
+    """{fusion instruction: op_name of every op its called computations
+    hold} for the fusions of the optimized HLO's non-fused computations."""
+    bodies, cur = {}, None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" ") and line.rstrip().endswith("{"):
+            cur = re.match(r"(?:ENTRY )?%?([\w.\-]+)", line).group(1)
+            bodies[cur] = []
+        elif cur is not None:
+            bodies[cur].append(line)
+    fusion_re = re.compile(
+        r"\s*(?:ROOT )?%?([\w.\-]+) = .* fusion\(.*calls=%?([\w.\-]+)")
+    fusions = {}
+    for comp, lines in bodies.items():
+        for line in lines:
+            m = fusion_re.match(line)
+            if m:
+                fusions[(comp, m.group(1))] = m.group(2)
+    fused = set(fusions.values())
+
+    def op_lines(comp, seen):
+        if comp in seen:
+            return []
+        seen.add(comp)
+        out = list(bodies.get(comp, ()))
+        for line in bodies.get(comp, ()):
+            for callee in re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)",
+                                     line):
+                out += op_lines(callee, seen)
+        return out
+
+    return {name: op_lines(callee, set())
+            for (comp, name), callee in fusions.items() if comp not in fused}
+
+
+@pytest.fixture(scope="module")
+def bert_large_step_hlo(one_chip):
+    """Optimized HLO of the program's own train step: 2 layers at
+    BERT-large widths, L=512, threefry dropout keys (so attention takes
+    the dense path and hashes every dropout mask on the VPU)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lddl_tpu.models import BertConfig, BertForPreTraining
+    from lddl_tpu.models.train import (TrainState, _init_variables,
+                                       make_optimizer,
+                                       make_sharded_train_step)
+
+    cfg = BertConfig.bert_large(num_layers=2, dropout_rng_impl="threefry")
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("dp",))
+    rep = NamedSharding(mesh, P())
+    batch_size, seq_len = 2, 512
+    tx = make_optimizer()
+
+    def init(rng):
+        ids = jnp.zeros((batch_size, seq_len), jnp.int32)
+        sample = {"input_ids": ids, "token_type_ids": ids,
+                  "attention_mask": ids}
+        params = nn.meta.unbox(_init_variables(
+            BertForPreTraining(cfg), rng, sample))["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=tx.init(params), tx=tx)
+
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch_size, seq_len), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp")))
+    batch = {"input_ids": tokens, "token_type_ids": tokens,
+             "attention_mask": tokens, "labels": tokens,
+             "next_sentence_labels": jax.ShapeDtypeStruct(
+                 (batch_size,), jnp.int32,
+                 sharding=NamedSharding(mesh, P("dp")))}
+    step = make_sharded_train_step(mesh, cfg)
+    return step.lower(state, batch, 0).compile().as_text()
+
+
+@pytest.mark.parametrize("site", [
+    "embeddings/Dropout_0",
+    "layer_{}/attention/Dropout_0",  # attention probabilities
+    "layer_{}/Dropout_0",            # attention output
+    "layer_{}/Dropout_1",            # FFN output
+])
+def test_dropout_mask_hashed_in_one_fusion(bert_large_step_hlo, site):
+    """Each dropout site's threefry hash (its ``xor`` ops, found by their
+    op_name metadata) sits in exactly one fusion: the backward reads the
+    stored keep-mask instead of re-hashing it in each consumer."""
+    fusions = _top_level_fusions(bert_large_step_hlo)
+    for path in sorted({site.format(i) for i in range(2)}):
+        scope = "/{}/".format(path)
+        hashing = sorted(
+            name for name, lines in fusions.items()
+            if any(" xor(" in ln and scope in ln for ln in lines))
+        assert len(hashing) == 1, (path, hashing)
