@@ -18,7 +18,16 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from .. import observability as obs
+from ..ops.flash_attention import (flash_attention, kernel_dropout_serves,
+                                   pad_seq_len, replays_bernoulli,
+                                   single_block_serves, threefry_key_words)
+
 with_logical = nn.with_logical_constraint
+
+# Trace-time counter: one per attention call traced into a program, by
+# the path taken (dense|flash|ring) and whether it drops probabilities.
+IMPL_SELECTED = "attention_impl_selected_total"
 
 
 class Dropout(nn.Dropout):
@@ -34,6 +43,12 @@ class Dropout(nn.Dropout):
     backward. The class keeps flax's name, so the sites are still
     auto-named ``Dropout_<n>`` and every ``make_rng`` path is unchanged.
     """
+
+    def key(self):
+        """The key ``__call__`` would draw, for a caller that applies the
+        mask itself (the flash kernels); pass it back as ``rng`` if the
+        site is applied after all."""
+        return self.make_rng(self.rng_collection)
 
     @nn.compact
     def __call__(self, inputs, deterministic=None, rng=None):
@@ -59,38 +74,46 @@ class Dropout(nn.Dropout):
 
 def resolve_auto_impl(seq_len, blockwise_ok, attention_dropout,
                       deterministic=False, *, head_dim):
-    """attention_impl="auto" -> "flash"|"dense". The thresholds come from
-    round-5 builder probes, not measured by the driver; their records were
-    removed and every number below is an unverified lead until a chip
-    benchmark re-measures it. The round-5 single-block kernels
-    (ops/flash_attention.py, fat (b, h)-row cells + one fused backward)
-    made the pallas path win or tie everywhere from L = 256 up — incl.
-    the reference's L=512 headline config that rounds 3-4 conceded to
-    XLA's fused dense attention (bert_base 45.2 vs 42.2 wall MFU,
-    bert_large parity within noise, round-5 chip probes), and the online
-    kernels keep their long-L wins (L=1024: 36.3 vs 34.0; L=2048: 35.6
-    vs 28.0, round 4). Dense stays ahead only at L <= 128 (52.1 vs 42.1
-    at the shortest bin) where per-kernel-launch overhead dominates.
-    The former in-between band (512 < L_pad < 1024, where the ONLINE
-    kernels lose — L=768 in-model probe 33.9 vs 38.1) was taken by
-    extending the single-block kernels to l_pad <= 896 with one-row
-    cells: kernel-level 1.71x over dense at L=768 and 1.51x at L=896,
-    in-model 46.4 vs 38.7 MFU at L=768, so only L_pad <= 128 remains
-    dense at the standard head_dim 64 (wider heads keep the 512 bound —
-    _use_onekv). Flash is picked only when it computes the SAME math as
-    dense (it skips
-    attention-prob dropout, so dropout > 0 pins dense — unless the call
-    is deterministic, where dropout is a no-op and flash is identical):
-    auto never changes the trained model, only the speed."""
-    from ..ops.flash_attention import pad_seq_len, single_block_serves
+    """attention_impl="auto" -> "flash"|"dense". Flash is picked only where
+    it computes the SAME math as dense, so auto never changes the trained
+    model, only the speed.
 
-    effective_dropout = 0.0 if deterministic else attention_dropout
-    # single_block_serves is the dispatcher's own predicate (incl. its
-    # head-dim gate), so the selector can never promise the single-block
-    # regime where flash_attention would fall back to the online kernels.
-    return ("flash" if blockwise_ok and effective_dropout == 0.0
-            and (single_block_serves(seq_len, head_dim)
-                 or pad_seq_len(seq_len) >= 1024) else "dense")
+    Without attention-probability dropout (or deterministic, where it is
+    a no-op): flash from L_pad 256 up. The thresholds come from round-5
+    chip probes that no benchmark cell re-measured: the single-block kernels
+    (ops/flash_attention.py, fat (b, h)-row cells + one fused backward)
+    win or tie from L_pad 256 through 896 (bert_base L=512 45.2 vs 42.2
+    wall MFU, bert_large parity; L=768 in-model 46.4 vs 38.7), and the
+    online kernels keep their long-L wins (L=1024: 36.3 vs 34.0; L=2048:
+    35.6 vs 28.0, round 4). Dense stays ahead at L_pad <= 128 (52.1 vs
+    42.1 at the shortest bin), where per-kernel-launch overhead dominates;
+    wider heads keep the 512 bound (_use_onekv).
+
+    With dropout: flash only where the single-block kernels serve, since
+    they draw the dense path's own keep-mask from the same threefry key
+    (the online kernels have no dropout, so L_pad >= 1024 stays dense) —
+    and only when the ambient mesh keeps the batch whole: a kernel under
+    a dp/fsdp split would count mask elements from its shard's rows, not
+    the batch's. A key of another implementation (rbg) cannot be replayed;
+    MultiHeadAttention keeps such calls dense."""
+    if not blockwise_ok:
+        return "dense"
+    if attention_dropout == 0.0 or deterministic:
+        # single_block_serves is the dispatcher's own predicate (incl. its
+        # head-dim gate), so the selector can never promise the
+        # single-block regime where flash_attention would fall back to the
+        # online kernels.
+        return ("flash" if single_block_serves(seq_len, head_dim)
+                or pad_seq_len(seq_len) >= 1024 else "dense")
+    return ("flash" if single_block_serves(seq_len, head_dim)
+            and not _batch_split() else "dense")
+
+
+def _batch_split():
+    """True when the ambient mesh shards the batch (dp or fsdp > 1)."""
+    from ..parallel.mesh import DATA_AXES
+    shape = jax.sharding.get_abstract_mesh().shape
+    return any(shape.get(axis, 1) > 1 for axis in DATA_AXES)
 
 
 class MultiHeadAttention(nn.Module):
@@ -135,14 +158,25 @@ class MultiHeadAttention(nn.Module):
         # padding mask; causal/cross calls always take the dense path.
         blockwise_ok = (q_input is kv_input and extra_bias is None
                         and padding_mask is not None)
+        # The attention-probability dropout site, Dropout_0 as ever: the
+        # dense path applies it, the single-block kernels take its key.
+        drop = Dropout(self.dropout)
+        rate = 0.0 if deterministic else self.dropout
         impl = self.attention_impl
         if impl == "auto":
             impl = resolve_auto_impl(q_input.shape[1], blockwise_ok,
                                      self.dropout, deterministic,
                                      head_dim=head_dim)
+        rng = None
+        if (impl == "flash" and blockwise_ok and rate > 0.0
+                and kernel_dropout_serves(q_input.shape[1], head_dim)):
+            rng = drop.key()
+            if self.attention_impl == "auto" and not replays_bernoulli(rng):
+                impl = "dense"    # the kernel cannot replay its mask
+            elif threefry_key_words(rng) is None:
+                rng = jax.random.bits(rng, (2,), jnp.uint32)
         use_ring = False
         if impl == "ring" and blockwise_ok:
-            import jax
             mesh = jax.sharding.get_abstract_mesh()
             use_ring = "sp" in mesh.axis_names and mesh.shape["sp"] > 1
         if segments is not None and use_ring:
@@ -152,8 +186,12 @@ class MultiHeadAttention(nn.Module):
             raise NotImplementedError(
                 "packed sequences (segments) are not supported with ring "
                 "attention; use attention_impl='flash' or 'dense'")
+        path = ("ring" if use_ring else
+                "flash" if impl == "flash" and blockwise_ok else "dense")
+        dropped = rate > 0.0 and (path == "dense" or rng is not None)
+        obs.inc(IMPL_SELECTED, impl=path, dropout="on" if dropped else "off")
 
-        if use_ring:
+        if path == "ring":
             # Sequence stays sharded: Q/K/V keep the "seq" axis on sp and
             # K/V blocks rotate around the ring. Attention-prob dropout is
             # skipped under ring (standard for blockwise kernels).
@@ -163,20 +201,22 @@ class MultiHeadAttention(nn.Module):
             k = split_heads(proj("key")(kv_input), "seq")
             v = split_heads(proj("value")(kv_input), "seq")
             ctx = ring_attention(q, k, v, padding_mask, mesh)
-        elif impl == "flash" and blockwise_ok:
-            # The pallas fused kernel (ops/flash_attention.py); attention-
-            # prob dropout is skipped, like ring. Packed rows hand the
-            # kernel per-token segment ids — the block-diagonal mask is
-            # enforced inside the kernel, no L x L mask materializes.
-            from ..ops.flash_attention import flash_attention
-
+        elif path == "flash":
+            # The pallas fused kernel (ops/flash_attention.py). The
+            # single-block kernels drop attention probabilities with the
+            # Dropout_0 site's key; the online ones skip it, like ring.
+            # Packed rows hand the kernel per-token segment ids — the
+            # block-diagonal mask is enforced inside the kernel, no L x L
+            # mask materializes.
             q = split_heads(proj("query")(q_input), None)
             k = split_heads(proj("key")(kv_input), None)
             v = split_heads(proj("value")(kv_input), None)
+            dropout = dict(dropout_rate=rate if dropped else 0.0,
+                           dropout_rng=rng)
             if segments is not None:
-                ctx = flash_attention(q, k, v, segments=segments)
+                ctx = flash_attention(q, k, v, segments=segments, **dropout)
             else:
-                ctx = flash_attention(q, k, v, padding_mask)
+                ctx = flash_attention(q, k, v, padding_mask, **dropout)
         else:
             # Full-sequence attention: entering this block the activations
             # all-gather from sp, and heads shard over tp.
@@ -203,8 +243,7 @@ class MultiHeadAttention(nn.Module):
                 bias = bias + extra_bias
             probs = nn.softmax(scores + jnp.asarray(bias, self.dtype),
                                axis=-1)
-            probs = Dropout(self.dropout)(probs,
-                                          deterministic=deterministic)
+            probs = drop(probs, deterministic=deterministic, rng=rng)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
         ctx = ctx.reshape(ctx.shape[0], ctx.shape[1],

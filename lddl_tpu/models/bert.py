@@ -89,17 +89,22 @@ class BertConfig:
     initializer_range: float = 0.02
     dtype: Any = jnp.bfloat16  # activations; params stay fp32
     # "auto" (default): dense at the shortest bins, the pallas flash
-    # kernel where it measurably wins or ties (L >= 256 since the
-    # round-5 single-block kernels) AND computes identical math
-    # (attention_dropout == 0 — flash skips prob dropout); the choice is
-    # per traced sequence length, so no config silently runs the slower
-    # impl (round-5 builder probe, not measured by the driver). "dense":
-    # all-gather from sp into full-sequence attention (Megatron-SP). "flash": always the pallas
-    # kernel. "ring": sequence-parallel exact attention — K/V blocks
-    # rotate over the sp ring (ops/ring_attention.py), no device ever
-    # holds the full sequence; attention-prob dropout is skipped under
-    # ring (standard for blockwise kernels). Falls back to dense when
-    # the mesh has no sp axis (or sp == 1).
+    # kernel where it wins or ties (L_pad >= 256 since the round-5
+    # single-block kernels; a round-5 chip probe no benchmark cell
+    # re-measured) AND computes identical math: with attention_dropout > 0
+    # only where the single-block kernels serve (L_pad 256-896 at
+    # head_dim 64), which draw the dense path's own keep-mask from a
+    # threefry key, and only on a mesh that keeps the batch whole; the
+    # choice is per traced sequence length (models.attention.
+    # resolve_auto_impl). "dense": all-gather from sp into full-sequence
+    # attention (Megatron-SP). "flash": always the pallas kernel; it
+    # skips attention-prob dropout where the online kernels serve (L_pad
+    # >= 1024 at head_dim 64). "ring": sequence-parallel exact
+    # attention — K/V blocks rotate over the sp ring
+    # (ops/ring_attention.py), no device ever holds the full sequence;
+    # attention-prob dropout is skipped under ring (standard for
+    # blockwise kernels). Falls back to dense when the mesh has no sp
+    # axis (or sp == 1).
     attention_impl: str = "auto"
     # Rematerialize each encoder layer on the backward pass
     # (jax.checkpoint): activations are recomputed instead of stored,
@@ -132,12 +137,15 @@ class BertConfig:
         if self.attention_impl in ("ring", "flash") \
                 and self.attention_dropout > 0:
             import warnings
+            where = ("" if self.attention_impl == "ring" else
+                     " where the online kernels serve (L_pad >= 1024 at "
+                     "head_dim 64)")
             warnings.warn(
-                "attention_impl='{}' skips attention-probability dropout "
+                "attention_impl='{}' skips attention-probability dropout{} "
                 "(standard for blockwise kernels): with attention_dropout="
-                "{} it trains a slightly different model than 'dense'. "
-                "Set attention_dropout=0.0 to silence this.".format(
-                    self.attention_impl, self.attention_dropout),
+                "{} it trains a slightly different model than 'dense' "
+                "there. Set attention_dropout=0.0 to silence this.".format(
+                    self.attention_impl, where, self.attention_dropout),
                 stacklevel=2)
 
     @staticmethod

@@ -15,6 +15,12 @@ what makes the pallas kernel BEAT XLA's fused dense attention from
 L_pad 256 through 896 (round-5 builder probe, not measured by the driver), where rounds 3-4 lost to it at 512 and
 below.
 
+The single-block kernels also drop attention probabilities: the forward
+hashes the keep-mask that ``jax.random.bernoulli`` would draw from the
+same threefry key, bit for bit, and stores it bit-packed for the
+backward (see "Attention-probability dropout" below). The online
+kernels have no dropout.
+
 Long sequences: the flash-style online-softmax kernels. Per (batch,
 head), Q blocks stream through VMEM while the kernel walks K/V blocks
 under a running-max/denominator softmax — the L x L score matrix never
@@ -33,7 +39,9 @@ runs on one (chip_smoke.py phase 4 checks it against dense).
 the real pallas lowering on every other backend.
 """
 
+import collections
 import functools
+import math
 
 # jax imported inside functions: the offline pipeline stages must stay
 # importable (via lddl_tpu.ops) on machines where jax is absent/broken.
@@ -221,13 +229,21 @@ def _from_bh(t, b, l, h, d):
     return t.reshape(b, h, -1, d).transpose(0, 2, 1, 3)[:, :l]
 
 
-def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None):
+def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None,
+                        dropout_rate=0.0, dropout_key=None):
     """Fused attention forward: q/k/v [B, L, H, D], kv_mask [B, L]
     (1 = attend, or per-token segment ids for packed rows — pass the same
     array as q_mask and attention becomes block-diagonal within rows).
-    Returns (out [B, L, H, D], lse [B*H, 1, L_pad]); fp32 accumulation,
-    output in q.dtype. L pads to a 128 multiple internally (padded keys
-    are masked; padded query rows are dropped on return)."""
+    Returns (out [B, L, H, D], lse [B*H, 1, L_pad], keep); fp32
+    accumulation, output in q.dtype. L pads to a 128 multiple internally
+    (padded keys are masked; padded query rows are dropped on return).
+
+    ``dropout_rate`` > 0 drops attention probabilities inside the
+    single-block kernels with the keep-mask ``jax.random.bernoulli(
+    dropout_key, 1 - dropout_rate, (B, H, L, L))`` draws, bit for bit
+    (``dropout_key``: the int32 words of a threefry key, see
+    ``threefry_key_words``); ``keep`` is that mask, bit-packed, for the
+    backward (None without dropout)."""
     import jax
     from jax.experimental import pallas as pl
     import jax.numpy as jnp
@@ -237,22 +253,15 @@ def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None):
     qb, kb, vb, maskb, qmaskb, (b, l, h, d, l_pad) = _prep(
         q, k, v, kv_mask, q_mask)
     scale = 1.0 / (d ** 0.5)
+    drop = _kernel_dropout(dropout_rate, b, l, h, d)
     if _use_onekv(l_pad, d):
-        nbh = _nbh_for(h, l_pad)
-        spec, spec_mask, spec_row = _onekv_specs(nbh, l_pad, d, h)
-        out, lse = pl.pallas_call(
-            functools.partial(_onekv_fwd_kernel, scale=scale, nbh=nbh),
-            name="flash_fwd_onekv",
-            grid=(b * h // nbh,),
-            in_specs=[spec, spec, spec, spec_mask, spec_mask],
-            out_specs=[spec, spec_row],
-            out_shape=[
-                jax.ShapeDtypeStruct((b * h, l_pad, d), q.dtype),
-                jax.ShapeDtypeStruct((b * h, 1, l_pad), jnp.float32),
-            ],
-            interpret=interpret,
-        )(qb, kb, vb, maskb, qmaskb)
-        return _from_bh(out, b, l, h, d), lse
+        args = [qb, kb, vb, maskb, qmaskb]
+        if drop is not None:
+            args.append(dropout_key)
+        res = _onekv_call(False, b, h, l_pad, d, (q.dtype,) * 3, drop,
+                          interpret)(*args)
+        keep = res[2] if drop is not None else None
+        return _from_bh(res[0], b, l, h, d), res[1], keep
     tq, tk = _block_sizes(l_pad)
     assert l_pad % tq == 0 and l_pad % tk == 0, (l_pad, tq, tk)
     kernel = functools.partial(_fwd_kernel, scale=scale,
@@ -279,15 +288,16 @@ def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None):
         ],
         interpret=interpret,
     )(qb, kb, vb, maskb, qmaskb)
-    return _from_bh(out, b, l, h, d), lse
+    return _from_bh(out, b, l, h, d), lse, None
 
 
 def flash_attention_bwd(q, k, v, kv_mask, out, lse, ct, interpret=None,
-                        q_mask=None):
+                        q_mask=None, dropout_rate=0.0, keep=None):
     """Pallas backward. Single-block regime: ONE fused kernel recomputes P
-    once and emits dQ, dK, dV together. Online regime: P recomputed
-    blockwise from (Q, K, LSE); dQ from a Q-block kernel, dK/dV from a
-    KV-block kernel."""
+    once and emits dQ, dK, dV together; with dropout it reads the
+    forward's stored ``keep`` mask (no second hash). Online regime: P
+    recomputed blockwise from (Q, K, LSE); dQ from a Q-block kernel,
+    dK/dV from a KV-block kernel."""
     import jax
     from jax.experimental import pallas as pl
     import jax.numpy as jnp
@@ -304,19 +314,13 @@ def flash_attention_bwd(q, k, v, kv_mask, out, lse, ct, interpret=None,
         axis=-1).reshape(b * h, 1, l_pad)
 
     if _use_onekv(l_pad, d):
-        nbh = _nbh_for(h, l_pad)
-        spec, spec_mask, spec_row = _onekv_specs(nbh, l_pad, d, h)
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_onekv_bwd_kernel, scale=scale, nbh=nbh),
-            name="flash_bwd_onekv",
-            grid=(b * h // nbh,),
-            in_specs=[spec, spec, spec, spec_mask, spec_mask, spec,
-                      spec_row, spec_row],
-            out_specs=[spec, spec, spec],
-            out_shape=[jax.ShapeDtypeStruct((b * h, l_pad, d), t.dtype)
-                       for t in (q, k, v)],
-            interpret=interpret,
-        )(qb, kb, vb, maskb, qmaskb, dob, lse, delta)
+        args = [qb, kb, vb, maskb, qmaskb, dob, lse, delta]
+        drop = _kernel_dropout(dropout_rate, b, l, h, d)
+        if drop is not None:
+            args.append(keep)
+        dq, dk, dv = _onekv_call(True, b, h, l_pad, d,
+                                 (q.dtype, k.dtype, v.dtype), drop,
+                                 interpret)(*args)
         return (_from_bh(dq, b, l, h, d), _from_bh(dk, b, l, h, d),
                 _from_bh(dv, b, l, h, d))
 
@@ -421,6 +425,13 @@ def single_block_serves(seq_len, head_dim):
     return l_pad >= 256 and _use_onekv(l_pad, head_dim)
 
 
+def kernel_dropout_serves(seq_len, head_dim):
+    """True when flash_attention drops attention probabilities for this
+    shape: the single-block kernels serve it (the online ones skip
+    dropout)."""
+    return _use_onekv(pad_seq_len(seq_len), head_dim)
+
+
 def _nbh_for(h, l_pad):
     """Rows per cell: largest of 4/2/1 dividing num_heads so every cell's
     rows share one batch row (mask built once per cell) — but 1 above
@@ -453,10 +464,17 @@ def _cell_bias(mask_ref, qmask_ref):
     return jnp.where(allowed, 0.0, -1e9)
 
 
-def _onekv_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, o_ref,
-                      lse_ref, *, scale, nbh):
+def _onekv_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, *refs,
+                      scale, nbh, drop):
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
+    if drop is None:
+        o_ref, lse_ref = refs
+    else:
+        key_ref, o_ref, lse_ref, keep_ref = refs
+        _store_keep_words(key_ref[0], key_ref[1], pl.program_id(0) * nbh,
+                          keep_ref, drop)
     bias = _cell_bias(mask_ref, qmask_ref)
     for i in range(nbh):
         q = q_ref[i]                             # [L, D], stored dtype
@@ -466,16 +484,25 @@ def _onekv_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, o_ref,
         m = s.max(axis=1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.maximum(p.sum(axis=1, keepdims=True), 1e-30)
+        inv = 1.0 / l
+        if drop is not None:
+            # Normalized by the undropped sum (l, lse), then dropped and
+            # scaled: select(mask, softmax / keep, 0), as Dropout does.
+            p = jnp.where(_unpack_keep(keep_ref[i], drop.pack), p, 0.0)
+            inv = inv * (1.0 / drop.keep_prob)
         o = _dot(p.astype(v.dtype), v)            # [L, D] fp32, unnormalized
-        o_ref[i] = (o * (1.0 / l)).astype(o_ref.dtype)
+        o_ref[i] = (o * inv).astype(o_ref.dtype)
         lse_ref[i, 0] = m[:, 0] + jnp.log(l[:, 0])
 
 
 def _onekv_bwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref,
-                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *, scale,
-                      nbh):
+                      lse_ref, delta_ref, *refs, scale, nbh, drop):
     import jax.numpy as jnp
 
+    if drop is None:
+        dq_ref, dk_ref, dv_ref = refs
+    else:
+        keep_ref, dq_ref, dk_ref, dv_ref = refs
     bias = _cell_bias(mask_ref, qmask_ref)
     for i in range(nbh):
         q = q_ref[i]
@@ -487,10 +514,225 @@ def _onekv_bwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref,
         s = _dot(q, k, transpose_b=True) * scale + bias
         p = jnp.exp(s - lse)                     # fp32 [Lq, Lk]
         dp = _dot(do, v, transpose_b=True)       # fp32 [Lq, Lk]
-        dv_ref[i] = _dot0(p.astype(do.dtype), do).astype(dv_ref.dtype)
+        pv = p                                   # what multiplied V
+        if drop is not None:
+            # out = (M*P/keep) V: dV = (M*P/keep)^T dO and
+            # dP = M*(dO V^T)/keep; dS = P*(dP - delta) holds as is, since
+            # delta = rowsum(dO*O) and O already carries the mask.
+            keep = _unpack_keep(keep_ref[i], drop.pack)
+            inv_keep = 1.0 / drop.keep_prob
+            pv = jnp.where(keep, p * inv_keep, 0.0)
+            dp = jnp.where(keep, dp * inv_keep, 0.0)
+        dv_ref[i] = _dot0(pv.astype(do.dtype), do).astype(dv_ref.dtype)
         ds = (p * (dp - delta) * scale).astype(k.dtype)
         dq_ref[i] = _dot(ds, k).astype(dq_ref.dtype)
         dk_ref[i] = _dot0(ds, q).astype(dk_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Attention-probability dropout inside the single-block kernels.
+#
+# The forward draws the keep-mask ``jax.random.bernoulli(key, 1 - rate,
+# (B, H, L, L))`` itself, bit for bit. With a threefry key (and
+# jax_threefry_partitionable, the default) element ((b*H + h)*L + q)*L + k
+# is kept iff ((x0 ^ x1) >> 9) < ceil(float32(1 - rate) * 2**23), where
+# (x0, x1) = threefry2x32(key, (0, that index)): the 23 bits ``uniform``
+# turns into a float in [0, 1). Rows and columns >= L are don't-care. The
+# mask is stored bit-packed for the backward, which never hashes again:
+# word [q', k] of a row holds in bit j the element of query row j*S + q'
+# (S = L_pad / pack), so packing and unpacking are elementwise shifts of
+# [S, L_pad] slabs.
+# ---------------------------------------------------------------------------
+
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+class _KernelDropout(collections.namedtuple(
+        "_KernelDropout", ("keep_prob", "seq_len", "pack"))):
+    """Static dropout parameters of a kernel."""
+
+    __slots__ = ()
+
+    @property
+    def threshold(self):
+        """bernoulli keeps an element iff its 23 uniform bits, read as an
+        integer, are below this."""
+        import numpy as np
+        return math.ceil(float(np.float32(self.keep_prob)) * 2 ** 23)
+
+
+def _kernel_dropout(rate, b, l, h, d):
+    """The kernels' dropout parameters, or None for rate 0."""
+    if rate == 0.0:
+        return None
+    l_pad = pad_seq_len(l)
+    if not 0.0 < rate < 1.0:
+        raise ValueError("dropout_rate must be in [0, 1): {}".format(rate))
+    if not _use_onekv(l_pad, d):
+        raise ValueError(
+            "attention-probability dropout runs in the single-block kernels "
+            "only (L_pad <= {} at head_dim 64); L={} head_dim={} takes the "
+            "online kernels".format(ONEKV_MAX_L_PAD, l, d))
+    if b * h * l * l > 2 ** 32:
+        raise ValueError(
+            "kernel dropout counts mask elements in 32 bits: B*H*L*L = {} "
+            "exceeds 2**32".format(b * h * l * l))
+    # 32 rows a word where the slab height S stays a multiple of the
+    # 8-row sublane tile, else 16 (l_pad is a multiple of 128).
+    pack = 32 if (l_pad // 32) % 8 == 0 else 16
+    return _KernelDropout(1.0 - rate, l, pack)
+
+
+def threefry_key_words(key):
+    """A threefry2x32 PRNG key (raw uint32[2] or typed) as the int32[2]
+    the kernels take, or None for a key of another implementation."""
+    import jax
+    import jax.numpy as jnp
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        if str(jax.random.key_impl(key)) != "threefry2x32":
+            return None
+        key = jax.random.key_data(key)
+    elif (key.shape != (2,)
+          or str(jax.config.jax_default_prng_impl) != "threefry2x32"):
+        return None
+    return jax.lax.bitcast_convert_type(key, jnp.int32)
+
+
+def replays_bernoulli(key):
+    """True when the kernels draw exactly the mask ``jax.random.bernoulli``
+    draws from ``key``: a threefry2x32 key, counted as
+    ``jax_threefry_partitionable`` (the default) counts."""
+    import jax
+    return (threefry_key_words(key) is not None
+            and jax.config.jax_threefry_partitionable)
+
+
+def _threefry2x32(k0, k1, x0, x1):
+    """jax.random's threefry2x32 hash in int32 arithmetic: adds wrap and
+    right shifts are logical, so every bit equals the uint32 original."""
+    import jax.lax as lax
+
+    def rotl(x, r):
+        return lax.shift_left(x, r) | lax.shift_right_logical(x, 32 - r)
+
+    ks = (k0, k1, k0 ^ k1 ^ 0x1BD11BDA)
+    x0 = x0 + ks[0]
+    x1 = x1 + ks[1]
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i % 2]:
+            x0 = x0 + x1
+            x1 = rotl(x1, r) ^ x0
+        x0 = x0 + ks[(i + 1) % 3]
+        x1 = x1 + (ks[(i + 2) % 3] + (i + 1))     # one add on the vector
+    return x0, x1
+
+
+def _keep_words(k0, k1, row, drop):
+    """Bit-packed keep-mask [S, L_pad] of kernel row ``row`` (= b*H + h),
+    hashed one [S, L_pad] slab (query rows S apart) at a time."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    l = drop.seq_len
+    l_pad = pad_seq_len(l)
+    s = l_pad // drop.pack
+    qi = lax.broadcasted_iota(jnp.int32, (s, l_pad), 0)
+    ki = lax.broadcasted_iota(jnp.int32, (s, l_pad), 1)
+    # Element counters of slab 0 (past 2**31 they wrap as uint32 would).
+    base = row * (l * l) + qi * l + ki
+
+    def body(j, words):
+        x0, x1 = _threefry2x32(k0, k1, 0, base + j * (s * l))
+        keep = lax.shift_right_logical(x0 ^ x1, 9) < drop.threshold
+        return jnp.where(keep, words | lax.shift_left(jnp.int32(1), j),
+                         words)
+
+    return lax.fori_loop(0, drop.pack, body,
+                         jnp.zeros((s, l_pad), jnp.int32))
+
+
+def _store_keep_words(k0, k1, row0, keep_ref, drop):
+    """Hash the keep-masks of kernel rows row0, row0 + 1, ... into
+    ``keep_ref`` [rows, S, L_pad]. A loop, not unrolled rows: the hash is
+    traced and lowered once per kernel, not once per row."""
+    import jax.lax as lax
+
+    def body(i, carry):
+        keep_ref[i] = _keep_words(k0, k1, row0 + i, drop)
+        return carry
+
+    lax.fori_loop(0, keep_ref.shape[0], body, 0)
+
+
+def _unpack_keep(words, pack):
+    """[S, L_pad] packed words -> [pack * S, L_pad] bool keep-mask."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+    s, l_pad = words.shape
+    bit = lax.broadcasted_iota(jnp.int32, (pack, s, l_pad), 0)
+    words = jnp.broadcast_to(words, (pack, s, l_pad))
+    return (lax.shift_right_logical(words, bit) & 1).reshape(
+        pack * s, l_pad) != 0
+
+
+def _dropout_specs(drop, nbh, l_pad, n_rows):
+    """(key spec, keep-mask spec, keep-mask shape) for grid (b*h // nbh,)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    s = l_pad // drop.pack
+    return (pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((nbh, s, l_pad), lambda g: (g, 0, 0)),
+            jax.ShapeDtypeStruct((n_rows, s, l_pad), jnp.int32))
+
+
+@functools.lru_cache(maxsize=64)
+def _onekv_call(backward, b, h, l_pad, d, dtypes, drop, interpret):
+    """The single-block forward (or backward) pallas_call of one static
+    configuration, under ``jax.jit(inline=True)`` and built once. Pallas
+    traces a kernel anew on every call (~0.17 s of host time), so a
+    model's layers would pay that in every program's set-up; the jitted
+    call traces once per configuration and, inlined, keeps each call
+    site's op name (the layer's scope). ``dtypes``: of dQ, dK, dV (the
+    forward's output takes the first)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    nbh = _nbh_for(h, l_pad)
+    spec, spec_mask, spec_row = _onekv_specs(nbh, l_pad, d, h)
+    in_specs = [spec, spec, spec, spec_mask, spec_mask]
+    if backward:
+        kernel, name = _onekv_bwd_kernel, "flash_bwd_onekv"
+        in_specs += [spec, spec_row, spec_row]
+        out_specs = [spec, spec, spec]
+        out_shape = [jax.ShapeDtypeStruct((b * h, l_pad, d), t)
+                     for t in dtypes]
+    else:
+        kernel, name = _onekv_fwd_kernel, "flash_fwd_onekv"
+        out_specs = [spec, spec_row]
+        out_shape = [jax.ShapeDtypeStruct((b * h, l_pad, d), dtypes[0]),
+                     jax.ShapeDtypeStruct((b * h, 1, l_pad), jnp.float32)]
+    if drop is not None:
+        spec_key, spec_keep, keep_shape = _dropout_specs(drop, nbh, l_pad,
+                                                         b * h)
+        if backward:
+            in_specs.append(spec_keep)
+        else:
+            in_specs.append(spec_key)
+            out_specs.append(spec_keep)
+            out_shape.append(keep_shape)
+    return jax.jit(pl.pallas_call(
+        functools.partial(kernel, scale=1.0 / (d ** 0.5), nbh=nbh,
+                          drop=drop),
+        name=name,
+        grid=(b * h // nbh,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+    ), inline=True)
 
 
 def _onekv_specs(nbh, l_pad, d, h):
@@ -514,22 +756,22 @@ def _build_vjp():
         return _FLASH_VJP
     import jax
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-    def fa(q, k, v, kv_mask, q_mask, interpret):
-        out, _ = flash_attention_fwd(q, k, v, kv_mask, interpret=interpret,
-                                     q_mask=q_mask)
-        return out
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+    def fa(q, k, v, kv_mask, q_mask, key, rate, interpret):
+        return fa_fwd(q, k, v, kv_mask, q_mask, key, rate, interpret)[0]
 
-    def fa_fwd(q, k, v, kv_mask, q_mask, interpret):
-        out, lse = flash_attention_fwd(q, k, v, kv_mask,
-                                       interpret=interpret, q_mask=q_mask)
-        return out, (q, k, v, kv_mask, q_mask, out, lse)
+    def fa_fwd(q, k, v, kv_mask, q_mask, key, rate, interpret):
+        out, lse, keep = flash_attention_fwd(
+            q, k, v, kv_mask, interpret=interpret, q_mask=q_mask,
+            dropout_rate=rate, dropout_key=key)
+        return out, (q, k, v, kv_mask, q_mask, out, lse, keep)
 
-    def fa_bwd(interpret, residuals, ct):
-        q, k, v, kv_mask, q_mask, out, lse = residuals
+    def fa_bwd(rate, interpret, residuals, ct):
+        q, k, v, kv_mask, q_mask, out, lse, keep = residuals
         dq, dk, dv = flash_attention_bwd(q, k, v, kv_mask, out, lse, ct,
-                                         interpret=interpret, q_mask=q_mask)
-        return dq, dk, dv, None, None
+                                         interpret=interpret, q_mask=q_mask,
+                                         dropout_rate=rate, keep=keep)
+        return dq, dk, dv, None, None, None
 
     fa.defvjp(fa_fwd, fa_bwd)
     _FLASH_VJP = fa
@@ -537,7 +779,7 @@ def _build_vjp():
 
 
 def flash_attention(q, k, v, kv_mask=None, interpret=None, q_mask=None,
-                    segments=None):
+                    segments=None, dropout_rate=0.0, dropout_rng=None):
     """Differentiable fused attention: pallas forward AND backward (see
     module docstring).
 
@@ -547,7 +789,12 @@ def flash_attention(q, k, v, kv_mask=None, interpret=None, q_mask=None,
     becomes block-diagonal per segment (0 = padding). Passing segment ids
     as ``kv_mask`` alone would silently attend across packed segments
     (ADVICE r4), which is why the packed path has its own keyword;
-    ``q_mask`` stays for callers composing the two sides explicitly."""
+    ``q_mask`` stays for callers composing the two sides explicitly.
+
+    ``dropout_rate`` > 0 (single-block shapes only) drops attention
+    probabilities with exactly the mask ``jax.random.bernoulli(
+    dropout_rng, 1 - dropout_rate, (B, H, L, L))`` draws, so the dense
+    path's ``Dropout`` with the same threefry key trains the same model."""
     if segments is not None:
         if kv_mask is not None or q_mask is not None:
             raise ValueError(
@@ -556,4 +803,10 @@ def flash_attention(q, k, v, kv_mask=None, interpret=None, q_mask=None,
         kv_mask, q_mask = segments, segments
     elif kv_mask is None:
         raise ValueError("flash_attention needs kv_mask or segments")
-    return _build_vjp()(q, k, v, kv_mask, q_mask, interpret)
+    key = None
+    if dropout_rate > 0.0:
+        key = threefry_key_words(dropout_rng)
+        if key is None:
+            raise ValueError("kernel dropout replays threefry2x32 keys only")
+    return _build_vjp()(q, k, v, kv_mask, q_mask, key, float(dropout_rate),
+                        interpret)

@@ -258,3 +258,106 @@ def test_bert_flash_matches_dense_logits():
                                rtol=5e-4, atol=5e-4)
     np.testing.assert_allclose(np.asarray(nsp_f), np.asarray(nsp_d),
                                rtol=5e-4, atol=5e-4)
+
+
+# --- attention-probability dropout inside the single-block kernels ------
+
+def _unpacked_keep(words, l):
+    """The forward's stored keep-mask [B*H, S, L_pad] (bit j of word
+    [q', k] is query row j*S + q') as bool [B*H, L, L]."""
+    w = np.asarray(words).view(np.uint32)
+    s, l_pad = w.shape[1:]
+    rows = [(w >> j) & 1 for j in range(l_pad // s)]
+    return np.concatenate(rows, axis=1).astype(bool)[:, :l, :l]
+
+
+@pytest.mark.parametrize("l,h", [(256, 4), (200, 4), (256, 2)],
+                         ids=["L256-nbh4", "L200-nbh4", "L256-nbh2"])
+def test_dropout_mask_is_bernoulli_bit_for_bit(l, h):
+    """The kernel draws exactly jax.random.bernoulli's [B, H, L, L] mask
+    for the same threefry key: aligned and unaligned L (L_pad 256), and
+    cells of 4 and 2 (batch, head) rows, whose global row index sets
+    each element's counter."""
+    from lddl_tpu.ops.flash_attention import (flash_attention_fwd,
+                                              threefry_key_words)
+    b = 2
+    q, k, v, mask = _inputs(b=b, l=l, h=h, dtype=jnp.bfloat16, seed=21)
+    key = jax.random.fold_in(jax.random.PRNGKey(9), 3)
+    _, _, words = flash_attention_fwd(
+        q, k, v, mask, dropout_rate=0.1,
+        dropout_key=threefry_key_words(key))
+    keep = _unpacked_keep(words, l).reshape(b, h, l, l)
+    want = np.asarray(jax.random.bernoulli(key, 0.9, (b, h, l, l)))
+    np.testing.assert_array_equal(keep, want)
+
+
+def _dense_dropout_attention(q, k, v, allowed, key, rate):
+    """The dense path's math in fp32: softmax, then the models' stored-mask
+    Dropout with ``key``, then the context."""
+    from lddl_tpu.models.attention import Dropout
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(scores + jnp.where(allowed, 0.0, -1e9), axis=-1)
+    probs = Dropout(rate).apply({}, probs, deterministic=False, rng=key)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["padding", "segments"])
+def test_dropout_matches_dense_dropout(packed):
+    """Forward and dQ/dK/dV of the kernels with dropout against the dense
+    path's Dropout-masked attention under the same key, at bf16 tolerance:
+    a padding mask, and packed rows (the mask does not depend on
+    segments). The loss reads the real tokens' rows, as training's does:
+    a packed row's padding queries (segment 0) attend to no key, and the
+    single-block backward's lse (~ -1e9) cannot hold their log-sum, so
+    their gradient is not dense's; their cotangent is zero in training."""
+    b, l, h = 2, 256, 4
+    q, k, v, mask = _inputs(b=b, l=l, h=h, dtype=jnp.bfloat16, seed=23)
+    key = jax.random.PRNGKey(31)
+    if packed:
+        segs = np.zeros((b, l), np.int32)
+        segs[0, :100], segs[0, 100:230] = 1, 2
+        segs[1, :250] = 1
+        segs = jnp.asarray(segs)
+        allowed = ((segs[:, None, :, None] == segs[:, None, None, :])
+                   & (segs[:, None, None, :] > 0))
+        real = (segs > 0)[:, :, None, None]
+        kw = dict(segments=segs)
+    else:
+        allowed = mask[:, None, None, :] > 0
+        real = True
+        kw = dict(kv_mask=mask)
+
+    def loss_f(q, k, v):
+        out = flash_attention(q, k, v, dropout_rate=0.1, dropout_rng=key,
+                              **kw)
+        return (jnp.where(real, out.astype(jnp.float32), 0.0) ** 2).sum(), out
+
+    def loss_d(q, k, v):
+        out = _dense_dropout_attention(q, k, v, allowed, key, 0.1)
+        return (jnp.where(real, out, 0.0) ** 2).sum(), out
+
+    (_, out), gf = jax.value_and_grad(loss_f, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    (_, ref), gd = jax.value_and_grad(loss_d, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+    for a, r in zip(gf, gd):
+        r = np.asarray(r)
+        np.testing.assert_allclose(np.asarray(a, np.float32), r, rtol=2e-2,
+                                   atol=2e-2 * np.abs(r).max())
+
+
+def test_dropout_needs_single_block_kernels_and_threefry():
+    """Kernel dropout is refused where the online kernels serve (they
+    draw no mask) and for a key whose mask it cannot replay."""
+    q, k, v, mask = _inputs(l=1000, h=1, seed=2)
+    with pytest.raises(ValueError, match="single-block"):
+        flash_attention(q, k, v, mask, dropout_rate=0.1,
+                        dropout_rng=jax.random.PRNGKey(0))
+    q, k, v, mask = _inputs(l=256, h=1, seed=2)
+    with pytest.raises(ValueError, match="threefry"):
+        flash_attention(q, k, v, mask, dropout_rate=0.1,
+                        dropout_rng=jax.random.key(0, impl="rbg"))

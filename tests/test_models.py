@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 
 from lddl_tpu.loader import to_device_batch
@@ -220,6 +221,107 @@ def test_attention_auto_selection(tiny_cfg):
                                   np.asarray(outs["dense"][0]))
     np.testing.assert_array_equal(np.asarray(outs["auto"][1]),
                                   np.asarray(outs["dense"][1]))
+
+
+def test_attention_auto_selection_with_dropout():
+    """With attention-probability dropout on, auto picks flash only where
+    the single-block kernels serve (L_pad 256-896 at head_dim 64), which
+    draw the dense path's own keep-mask; dense at L_pad <= 128, at L_pad
+    >= 1024 (the online kernels draw no mask), for causal/cross calls, and
+    under a mesh that splits the batch."""
+    from lddl_tpu.models.attention import resolve_auto_impl
+
+    assert resolve_auto_impl(128, True, 0.1, head_dim=64) == "dense"
+    for seq_len in (200, 256, 512, 768, 896):
+        assert resolve_auto_impl(seq_len, True, 0.1,
+                                 head_dim=64) == "flash", seq_len
+    for seq_len in (960, 1024, 2048):
+        assert resolve_auto_impl(seq_len, True, 0.1,
+                                 head_dim=64) == "dense", seq_len
+    assert resolve_auto_impl(512, False, 0.1, head_dim=64) == "dense"
+    devices = jax.devices()
+    for axes in ({"dp": 2}, {"fsdp": 2}, {"dp": 2, "fsdp": 2, "tp": 2}):
+        n = int(np.prod(list(axes.values())))
+        with jax.set_mesh(make_mesh(axes, devices[:n])):
+            assert resolve_auto_impl(512, True, 0.1,
+                                     head_dim=64) == "dense", axes
+            # without dropout there is no mask to keep whole
+            assert resolve_auto_impl(512, True, 0.0,
+                                     head_dim=64) == "flash", axes
+    with jax.set_mesh(make_mesh({"dp": 1, "tp": 2}, devices[:2])):
+        assert resolve_auto_impl(512, True, 0.1, head_dim=64) == "flash"
+
+
+def _selected(model, batch, seq_len, dropout_rng=None):
+    """attention_impl_selected_total's values from tracing one training
+    forward of ``model`` at ``seq_len``."""
+    from lddl_tpu import observability as obs
+    from lddl_tpu.models.attention import IMPL_SELECTED
+
+    b = {k: batch[k][:, :seq_len]
+         for k in ("input_ids", "token_type_ids", "attention_mask")}
+    obs.registry().reset()
+    if dropout_rng is None:
+        dropout_rng = jax.random.PRNGKey(1)
+    jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0), "dropout": dropout_rng},
+        b["input_ids"], b["token_type_ids"], b["attention_mask"],
+        deterministic=False))
+    return obs.registry().get(IMPL_SELECTED).snapshot()["values"]
+
+
+def test_auto_flash_dropout_matches_dense(monkeypatch, tmp_path):
+    """A tiny BERT at L=256 with dropout on: auto (flash, the kernels
+    drawing the masks) against dense under the same params and dropout
+    rng, loss and gradients within bf16 tolerance. The trace-time counter
+    reads flash with dropout on every layer at L=256, dense at L=128, and
+    dense for an rbg key, whose mask the kernel cannot replay."""
+    import flax.linen as nn
+
+    seq_len = 256
+    batch = fake_pretrain_batch(512, 2, seq_len, seed=4)
+    batch["attention_mask"][1, 200:] = 0
+    rngs = {"dropout": jax.random.PRNGKey(5)}
+    results = {}
+    for impl in ("auto", "dense"):
+        cfg = BertConfig.tiny(attention_impl=impl,
+                              max_position_embeddings=seq_len)
+        model = BertForPreTraining(cfg)
+        params = nn.meta.unbox(model.init(
+            jax.random.PRNGKey(0), batch["input_ids"],
+            batch["token_type_ids"], batch["attention_mask"],
+            deterministic=True))["params"]
+
+        def loss(p):
+            mlm, nsp = model.apply(
+                {"params": p}, batch["input_ids"], batch["token_type_ids"],
+                batch["attention_mask"], deterministic=False, rngs=rngs)
+            return (jnp.square(mlm.astype(jnp.float32)).mean()
+                    + jnp.square(nsp.astype(jnp.float32)).mean())
+
+        results[impl] = jax.jit(jax.value_and_grad(loss))(params)
+    (la, ga), (ld, gd) = results["auto"], results["dense"]
+    np.testing.assert_allclose(float(la), float(ld), rtol=1e-2)
+    scale = max(float(jnp.abs(g).max()) for g in jax.tree.leaves(gd))
+    for a, d in zip(jax.tree.leaves(ga), jax.tree.leaves(gd)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(d),
+                                   rtol=2e-2, atol=1e-2 * scale)
+
+    monkeypatch.setenv("LDDL_TPU_METRICS_DIR", str(tmp_path))
+    model = BertForPreTraining(BertConfig.tiny(
+        max_position_embeddings=seq_len))
+    layers = model.cfg.num_layers
+    try:
+        assert _selected(model, batch, 256) == {
+            "dropout=on,impl=flash": layers}
+        assert _selected(model, batch, 128) == {
+            "dropout=on,impl=dense": layers}
+        assert _selected(model, batch, 256,
+                         jax.random.key(1, impl="rbg")) == {
+            "dropout=on,impl=dense": layers}
+    finally:
+        from lddl_tpu import observability as obs
+        obs.registry().reset()
 
 
 @pytest.mark.slow  # ~37s: full compile+train on CPU devices, budget-gated from tier-1

@@ -5,13 +5,15 @@ Interpret-mode tests cannot see what the chip's compiler refuses: slices
 off the tiling, more VMEM than a kernel may use. Each flash test compiles
 forward+backward at real widths (bf16, 12 heads) for one chip of a
 v5e:2x2 topology and checks the program holds the kernel; the dropout
-test reads how the chip's compiler fused a BERT-large step. The topology is
+tests compile the kernels that draw the probability masks and read how
+the chip's compiler fused a BERT-large step. The topology is
 described inside a fixture of this one file: only one process may load
 the TPU library, so describing it at import would fail on every other
 test worker and change what each collects.
 """
 
 import re
+import sys
 
 import flax.linen as nn
 import jax
@@ -119,8 +121,9 @@ def _top_level_fusions(hlo_text):
 @pytest.fixture(scope="module")
 def bert_large_step_hlo(one_chip):
     """Optimized HLO of the program's own train step: 2 layers at
-    BERT-large widths, L=512, threefry dropout keys (so attention takes
-    the dense path and hashes every dropout mask on the VPU)."""
+    BERT-large widths, L=512, threefry dropout keys: attention takes the
+    single-block flash kernels, which draw the probability masks, and
+    XLA hashes the hidden dropout masks on the VPU."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -130,6 +133,8 @@ def bert_large_step_hlo(one_chip):
                                        make_sharded_train_step)
 
     cfg = BertConfig.bert_large(num_layers=2, dropout_rng_impl="threefry")
+    # The backend is still the CPU: lower the kernels for the chip.
+    flash_module = sys.modules["lddl_tpu.ops.flash_attention"]
     mesh = Mesh(np.array(list(one_chip.device_set)), ("dp",))
     rep = NamedSharding(mesh, P())
     batch_size, seq_len = 2, 512
@@ -155,7 +160,9 @@ def bert_large_step_hlo(one_chip):
                  (batch_size,), jnp.int32,
                  sharding=NamedSharding(mesh, P("dp")))}
     step = make_sharded_train_step(mesh, cfg)
-    return step.lower(state, batch, 0).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(flash_module, "_interpret_default", lambda: False)
+        return step.lower(state, batch, 0).compile().as_text()
 
 
 @pytest.mark.parametrize("site", [
@@ -165,13 +172,64 @@ def bert_large_step_hlo(one_chip):
     "layer_{}/Dropout_1",            # FFN output
 ])
 def test_dropout_mask_hashed_in_one_fusion(bert_large_step_hlo, site):
-    """Each dropout site's threefry hash (its ``xor`` ops, found by their
-    op_name metadata) sits in exactly one fusion: the backward reads the
-    stored keep-mask instead of re-hashing it in each consumer."""
+    """Each hidden dropout site's threefry hash (its ``xor`` ops, found by
+    their op_name metadata) sits in exactly one fusion: the backward reads
+    the stored keep-mask instead of re-hashing it in each consumer. The
+    attention probabilities' mask is hashed inside the flash forward
+    kernel: no XLA fusion of the layer's attention scope hashes, and the
+    backward kernel takes the stored mask as an operand."""
     fusions = _top_level_fusions(bert_large_step_hlo)
     for path in sorted({site.format(i) for i in range(2)}):
+        if path.endswith("/attention/Dropout_0"):
+            scope = "/{}/".format(path[:-len("/Dropout_0")])
+            hashing = sorted(
+                name for name, lines in fusions.items()
+                if any(" xor(" in ln and scope in ln for ln in lines))
+            assert hashing == [], (path, hashing)
+            # B*H = 2*16 rows of [L_pad/32, L_pad] packed words.
+            keep = "s32[32,16,512]"
+            _assert_mask_passes(bert_large_step_hlo, keep, scope)
+            continue
         scope = "/{}/".format(path)
         hashing = sorted(
             name for name, lines in fusions.items()
             if any(" xor(" in ln and scope in ln for ln in lines))
         assert len(hashing) == 1, (path, hashing)
+
+
+def test_flash_dropout_compiles_for_v5e(one_chip):
+    """The dropout kernels at BERT-large's widths and batch (B=16, H=16,
+    L=512, D=64): forward and backward compile for a v5e, the forward
+    returns the packed keep-mask and the backward takes it."""
+    from lddl_tpu.ops.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((16, 512, 16, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((16, 512), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def loss(q, k, v, m, key):
+        out = flash_attention(q, k, v, m, interpret=False, dropout_rate=0.1,
+                              dropout_rng=key)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, mask, key).compile().as_text()
+    _assert_mask_passes(text, "s32[256,16,512]")
+
+
+def _assert_mask_passes(hlo_text, keep, scope=""):
+    """The flash forward kernel under ``scope`` returns the packed
+    keep-mask (shape ``keep``) and its backward takes it as an operand."""
+    calls = {}
+    for name in ("flash_fwd_onekv", "flash_bwd_onekv"):
+        lines = [ln for ln in hlo_text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln
+                 and scope + name in ln]
+        assert len(lines) == 1, (name, scope, len(lines))
+        calls[name] = lines[0]
+    outputs = calls["flash_fwd_onekv"].split(" custom-call(")[0]
+    operands = calls["flash_bwd_onekv"].split(
+        "operand_layout_constraints={")[1].split("}, ")
+    assert keep in outputs, outputs
+    assert any(op.startswith(keep) for op in operands), operands
