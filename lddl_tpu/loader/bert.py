@@ -25,7 +25,7 @@ from ..utils.fs import (
     get_generation_of_path,
 )
 from ..utils.logging import DatasetLogger
-from .dataloader import Binned, DataLoader
+from .dataloader import Binned, DataLoader, count_dropout_mask_tiles
 from .datasets import (ParquetDataset, annotate_quarantine,
                        verified_shard_paths)
 
@@ -775,6 +775,10 @@ class PackedBertLoader:
                             stats["total_tokens"])
                     obs.set_gauge("loader_padding_efficiency",
                                   1.0 - self.pad_ratio)
+                    count_dropout_mask_tiles(
+                        obs.registry().counter(
+                            "attention_dropout_mask_tiles_total"),
+                        batch["attention_mask"])
                 yield batch
 
         def seg_len(v):

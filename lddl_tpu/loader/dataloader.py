@@ -38,7 +38,7 @@ class _EpochObserver:
     when telemetry is enabled."""
 
     __slots__ = ("_latency", "_batches", "_samples", "_real", "_padded",
-                 "_gauge")
+                 "_gauge", "_mask_tiles")
 
     def __init__(self):
         reg = obs.registry()
@@ -48,6 +48,7 @@ class _EpochObserver:
         self._real = reg.counter("loader_real_tokens_total")
         self._padded = reg.counter("loader_padded_slots_total")
         self._gauge = reg.gauge("loader_padding_efficiency")
+        self._mask_tiles = reg.counter("attention_dropout_mask_tiles_total")
 
     def batch(self, batch, dt_s):
         self._latency.observe(dt_s)
@@ -57,6 +58,7 @@ class _EpochObserver:
             self._samples.inc(len(mask))
             self._real.inc(int(mask.sum()))
             self._padded.inc(int(mask.size))
+            count_dropout_mask_tiles(self._mask_tiles, mask)
         elif isinstance(batch, (list, tuple)):
             self._samples.inc(len(batch))
 
@@ -66,6 +68,16 @@ class _EpochObserver:
         padded = self._padded.total()
         if padded:
             self._gauge.set(self._real.total() / padded)
+
+
+def count_dropout_mask_tiles(counter, attention_mask):
+    """Count the attention-dropout mask tiles the single-block flash
+    forward hashes and skips for one batch's [B, L] ``attention_mask``
+    (``tile="hashed"|"skipped"``)."""
+    from ..ops.flash_attention import dropout_mask_tiles
+    hashed, skipped = dropout_mask_tiles(attention_mask)
+    counter.inc(hashed, tile="hashed")
+    counter.inc(skipped, tile="skipped")
 
 
 def _timed_collate(collate):

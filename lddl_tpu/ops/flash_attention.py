@@ -17,9 +17,9 @@ below.
 
 The single-block kernels also drop attention probabilities: the forward
 hashes the keep-mask that ``jax.random.bernoulli`` would draw from the
-same threefry key, bit for bit, and stores it bit-packed for the
-backward (see "Attention-probability dropout" below). The online
-kernels have no dropout.
+same threefry key, bit for bit within each row's valid extent, and
+stores it bit-packed for the backward (see "Attention-probability
+dropout" below). The online kernels have no dropout.
 
 Long sequences: the flash-style online-softmax kernels. Per (batch,
 head), Q blocks stream through VMEM while the kernel walks K/V blocks
@@ -241,9 +241,12 @@ def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None,
     ``dropout_rate`` > 0 drops attention probabilities inside the
     single-block kernels with the keep-mask ``jax.random.bernoulli(
     dropout_key, 1 - dropout_rate, (B, H, L, L))`` draws, bit for bit
-    (``dropout_key``: the int32 words of a threefry key, see
-    ``threefry_key_words``); ``keep`` is that mask, bit-packed, for the
-    backward (None without dropout)."""
+    within each row's valid extent (``dropout_key``: the int32 words of a
+    threefry key, see ``threefry_key_words``); ``keep`` is that mask,
+    bit-packed, for the backward (None without dropout). A row's extent
+    ends at its last valid key: query rows past it are padding, whose
+    output is not defined under dropout and whose cotangent must be 0,
+    as every loss that ignores padding gives."""
     import jax
     from jax.experimental import pallas as pl
     import jax.numpy as jnp
@@ -257,7 +260,7 @@ def flash_attention_fwd(q, k, v, kv_mask, interpret=None, q_mask=None,
     if _use_onekv(l_pad, d):
         args = [qb, kb, vb, maskb, qmaskb]
         if drop is not None:
-            args.append(dropout_key)
+            args += [dropout_key, _row_extent(maskb[:, 0], jnp)]
         res = _onekv_call(False, b, h, l_pad, d, (q.dtype,) * 3, drop,
                           interpret)(*args)
         keep = res[2] if drop is not None else None
@@ -465,16 +468,17 @@ def _cell_bias(mask_ref, qmask_ref):
 
 
 def _onekv_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, *refs,
-                      scale, nbh, drop):
+                      scale, nbh, heads, drop):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     if drop is None:
         o_ref, lse_ref = refs
     else:
-        key_ref, o_ref, lse_ref, keep_ref = refs
-        _store_keep_words(key_ref[0], key_ref[1], pl.program_id(0) * nbh,
-                          keep_ref, drop)
+        key_ref, extent_ref, o_ref, lse_ref, keep_ref = refs
+        row0 = pl.program_id(0) * nbh
+        _store_keep_words(key_ref[0], key_ref[1], row0,
+                          extent_ref[row0 // heads], keep_ref, drop)
     bias = _cell_bias(mask_ref, qmask_ref)
     for i in range(nbh):
         q = q_ref[i]                             # [L, D], stored dtype
@@ -533,16 +537,24 @@ def _onekv_bwd_kernel(q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref,
 # Attention-probability dropout inside the single-block kernels.
 #
 # The forward draws the keep-mask ``jax.random.bernoulli(key, 1 - rate,
-# (B, H, L, L))`` itself, bit for bit. With a threefry key (and
-# jax_threefry_partitionable, the default) element ((b*H + h)*L + q)*L + k
-# is kept iff ((x0 ^ x1) >> 9) < ceil(float32(1 - rate) * 2**23), where
-# (x0, x1) = threefry2x32(key, (0, that index)): the 23 bits ``uniform``
-# turns into a float in [0, 1). Rows and columns >= L are don't-care. The
-# mask is stored bit-packed for the backward, which never hashes again:
-# word [q', k] of a row holds in bit j the element of query row j*S + q'
-# (S = L_pad / pack), so packing and unpacking are elementwise shifts of
-# [S, L_pad] slabs.
+# (B, H, L, L))`` itself, bit for bit within each batch row's valid
+# extent. With a threefry key (and jax_threefry_partitionable, the
+# default) element ((b*H + h)*L + q)*L + k is kept iff ((x0 ^ x1) >> 9) <
+# ceil(float32(1 - rate) * 2**23), where (x0, x1) = threefry2x32(key, (0,
+# that index)): the 23 bits ``uniform`` turns into a float in [0, 1).
+# Rows and columns >= L are don't-care. The mask is stored bit-packed for
+# the backward, which never hashes again: word [q', k] of a row holds in
+# bit j the element of query row j*S + q' (S = L_pad / pack), so packing
+# and unpacking are elementwise shifts of [S, L_pad] slabs.
+#
+# A row's extent n is one past its last valid key. Keys >= n take an exact
+# 0 probability whatever their bit, and queries >= n are padding, which no
+# loss reads (their cotangent is 0, so they add nothing to dQ, dK, dV). So
+# the forward hashes only the tiles of query slabs j < ceil(n / S) by
+# 128-lane key blocks c with c*128 < n, and stores zeros elsewhere.
 # ---------------------------------------------------------------------------
+
+_KEY_TILE = 128
 
 _THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
 
@@ -577,10 +589,39 @@ def _kernel_dropout(rate, b, l, h, d):
         raise ValueError(
             "kernel dropout counts mask elements in 32 bits: B*H*L*L = {} "
             "exceeds 2**32".format(b * h * l * l))
-    # 32 rows a word where the slab height S stays a multiple of the
-    # 8-row sublane tile, else 16 (l_pad is a multiple of 128).
-    pack = 32 if (l_pad // 32) % 8 == 0 else 16
-    return _KernelDropout(1.0 - rate, l, pack)
+    return _KernelDropout(1.0 - rate, l, _pack_for(l_pad))
+
+
+def _pack_for(l_pad):
+    """Query rows a keep-mask word holds: 32 where the slab height S stays
+    a multiple of the 8-row sublane tile, else 16 (l_pad is a multiple of
+    128)."""
+    return 32 if (l_pad // 32) % 8 == 0 else 16
+
+
+def _row_extent(mask, xp):
+    """Each row's valid extent for a [B, L] key mask (> 0 = valid): one
+    past its last valid index, 0 for an all-padding row. ``xp`` is numpy
+    or jax.numpy, so the host's count and the kernel's bound are one rule.
+    The last valid index, not a count: a row with holes still hashes every
+    live entry."""
+    pos = xp.arange(1, mask.shape[1] + 1, dtype=xp.int32)
+    return xp.max(xp.where(mask > 0, pos, 0), axis=1).astype(xp.int32)
+
+
+def dropout_mask_tiles(attention_mask):
+    """(hashed, skipped): the keep-mask tiles, one query slab by one
+    128-lane key block of a batch row, that the single-block forward
+    hashes and skips for the [B, L] ``attention_mask`` (the extent rule of
+    ``_store_keep_words``; each of a row's heads repeats its count)."""
+    import numpy as np
+    mask = np.asarray(attention_mask)
+    b, l = mask.shape
+    l_pad = pad_seq_len(l)
+    s = l_pad // _pack_for(l_pad)
+    n = _row_extent(mask, np).astype(np.int64)
+    hashed = int((-(-n // s) * -(-n // _KEY_TILE)).sum())
+    return hashed, b * (l_pad // s) * (l_pad // _KEY_TILE) - hashed
 
 
 def threefry_key_words(key):
@@ -627,19 +668,23 @@ def _threefry2x32(k0, k1, x0, x1):
     return x0, x1
 
 
-def _keep_words(k0, k1, row, drop):
-    """Bit-packed keep-mask [S, L_pad] of kernel row ``row`` (= b*H + h),
-    hashed one [S, L_pad] slab (query rows S apart) at a time."""
+def _keep_words(k0, k1, row0, col0, n_slabs, shape, drop):
+    """Bit-packed keep-mask words [rows, S, 128] of kernel rows row0,
+    row0 + 1, ... (= b*H + h) at key columns col0 .. col0 + 127, hashed
+    one [rows, S, 128] tile of query slabs (rows S apart) at a time for
+    the first ``n_slabs`` slabs; the bits of later slabs stay 0. Stacking
+    the cell's rows keeps as many vregs in flight as a whole [S, L_pad]
+    slab of one row."""
     import jax.lax as lax
     import jax.numpy as jnp
 
     l = drop.seq_len
-    l_pad = pad_seq_len(l)
-    s = l_pad // drop.pack
-    qi = lax.broadcasted_iota(jnp.int32, (s, l_pad), 0)
-    ki = lax.broadcasted_iota(jnp.int32, (s, l_pad), 1)
+    s = shape[1]
+    ri = lax.broadcasted_iota(jnp.int32, shape, 0)
+    qi = lax.broadcasted_iota(jnp.int32, shape, 1)
+    ki = lax.broadcasted_iota(jnp.int32, shape, 2)
     # Element counters of slab 0 (past 2**31 they wrap as uint32 would).
-    base = row * (l * l) + qi * l + ki
+    base = (row0 + ri) * (l * l) + qi * l + (ki + col0)
 
     def body(j, words):
         x0, x1 = _threefry2x32(k0, k1, 0, base + j * (s * l))
@@ -647,21 +692,33 @@ def _keep_words(k0, k1, row, drop):
         return jnp.where(keep, words | lax.shift_left(jnp.int32(1), j),
                          words)
 
-    return lax.fori_loop(0, drop.pack, body,
-                         jnp.zeros((s, l_pad), jnp.int32))
+    return lax.fori_loop(0, n_slabs, body, jnp.zeros(shape, jnp.int32))
 
 
-def _store_keep_words(k0, k1, row0, keep_ref, drop):
+def _store_keep_words(k0, k1, row0, n, keep_ref, drop):
     """Hash the keep-masks of kernel rows row0, row0 + 1, ... into
-    ``keep_ref`` [rows, S, L_pad]. A loop, not unrolled rows: the hash is
-    traced and lowered once per kernel, not once per row."""
-    import jax.lax as lax
+    ``keep_ref`` [rows, S, L_pad] within the rows' valid extent ``n``
+    (they share one batch row): query slabs j < ceil(n / S), a dynamic
+    loop bound, by key blocks c*128 < n, each at a static lane offset
+    under its own guard. Every word is written: a skipped key block stores
+    zeros, so the stored mask is deterministic."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    def body(i, carry):
-        keep_ref[i] = _keep_words(k0, k1, row0 + i, drop)
-        return carry
+    rows, s, l_pad = keep_ref.shape
+    shape = (rows, s, _KEY_TILE)
+    n_slabs = (n + s - 1) // s
+    for col0 in range(0, l_pad, _KEY_TILE):
+        lanes = pl.ds(col0, _KEY_TILE)
 
-    lax.fori_loop(0, keep_ref.shape[0], body, 0)
+        @pl.when(col0 < n)
+        def _hash():
+            keep_ref[:, :, lanes] = _keep_words(k0, k1, row0, col0, n_slabs,
+                                                shape, drop)
+
+        @pl.when(col0 >= n)
+        def _skip():
+            keep_ref[:, :, lanes] = jnp.zeros(shape, jnp.int32)
 
 
 def _unpack_keep(words, pack):
@@ -676,7 +733,8 @@ def _unpack_keep(words, pack):
 
 
 def _dropout_specs(drop, nbh, l_pad, n_rows):
-    """(key spec, keep-mask spec, keep-mask shape) for grid (b*h // nbh,)."""
+    """(SMEM spec, keep-mask spec, keep-mask shape) for grid (b*h // nbh,):
+    the SMEM spec takes the key words and the rows' valid extents whole."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -703,6 +761,7 @@ def _onekv_call(backward, b, h, l_pad, d, dtypes, drop, interpret):
     nbh = _nbh_for(h, l_pad)
     spec, spec_mask, spec_row = _onekv_specs(nbh, l_pad, d, h)
     in_specs = [spec, spec, spec, spec_mask, spec_mask]
+    params = dict(scale=1.0 / (d ** 0.5), nbh=nbh, drop=drop)
     if backward:
         kernel, name = _onekv_bwd_kernel, "flash_bwd_onekv"
         in_specs += [spec, spec_row, spec_row]
@@ -711,21 +770,21 @@ def _onekv_call(backward, b, h, l_pad, d, dtypes, drop, interpret):
                      for t in dtypes]
     else:
         kernel, name = _onekv_fwd_kernel, "flash_fwd_onekv"
+        params["heads"] = h
         out_specs = [spec, spec_row]
         out_shape = [jax.ShapeDtypeStruct((b * h, l_pad, d), dtypes[0]),
                      jax.ShapeDtypeStruct((b * h, 1, l_pad), jnp.float32)]
     if drop is not None:
-        spec_key, spec_keep, keep_shape = _dropout_specs(drop, nbh, l_pad,
-                                                         b * h)
+        spec_smem, spec_keep, keep_shape = _dropout_specs(drop, nbh, l_pad,
+                                                          b * h)
         if backward:
             in_specs.append(spec_keep)
         else:
-            in_specs.append(spec_key)
+            in_specs += [spec_smem, spec_smem]     # key words, extents
             out_specs.append(spec_keep)
             out_shape.append(keep_shape)
     return jax.jit(pl.pallas_call(
-        functools.partial(kernel, scale=1.0 / (d ** 0.5), nbh=nbh,
-                          drop=drop),
+        functools.partial(kernel, **params),
         name=name,
         grid=(b * h // nbh,),
         in_specs=in_specs,
@@ -792,9 +851,12 @@ def flash_attention(q, k, v, kv_mask=None, interpret=None, q_mask=None,
     ``q_mask`` stays for callers composing the two sides explicitly.
 
     ``dropout_rate`` > 0 (single-block shapes only) drops attention
-    probabilities with exactly the mask ``jax.random.bernoulli(
-    dropout_rng, 1 - dropout_rate, (B, H, L, L))`` draws, so the dense
-    path's ``Dropout`` with the same threefry key trains the same model."""
+    probabilities with the mask ``jax.random.bernoulli(dropout_rng, 1 -
+    dropout_rate, (B, H, L, L))`` draws, bit for bit within each row's
+    valid extent (up to its last valid key), so the dense path's
+    ``Dropout`` with the same threefry key trains the same model: the
+    real tokens' outputs and, with a zero cotangent on padding rows, the
+    gradients are the same."""
     if segments is not None:
         if kv_mask is not None or q_mask is not None:
             raise ValueError(

@@ -527,6 +527,16 @@ def test_rule_ids_registered():
 # ------------------------------------- regression pins for real-tree fixes
 
 
+def _unset_fleet_env():
+    """Drop what ``fleet.configure`` and ``fleet.ensure_started`` put in
+    the environment, the metrics directory among it: left set, it arms
+    telemetry for every later test of the worker."""
+    for name in ("LDDL_TPU_FLEET_DIR", "LDDL_TPU_FLEET_HOLDER",
+                 "LDDL_TPU_FLEET_TTL_S", "LDDL_TPU_FLEET_INTERVAL_S",
+                 "LDDL_TPU_FLEET_HEARTBEAT_S", "LDDL_TPU_METRICS_DIR"):
+        os.environ.pop(name, None)
+
+
 def test_concurrent_flush_events_loses_nothing(tmp_path):
     """fleet.flush_events raced the heartbeat thread on the shared
     _ev_segment dict (rotating_path mutates it outside _lock before the
@@ -570,7 +580,7 @@ def test_concurrent_flush_events_loses_nothing(tmp_path):
                                      for i in range(n_events))
     finally:
         fleet._reset_for_tests()
-        os.environ.pop("LDDL_TPU_FLEET_DIR", None)
+        _unset_fleet_env()
 
 
 def test_concurrent_series_flush_loses_nothing(tmp_path):
@@ -612,7 +622,7 @@ def test_concurrent_series_flush_loses_nothing(tmp_path):
         assert got == n_points
     finally:
         fleet._reset_for_tests()
-        os.environ.pop("LDDL_TPU_FLEET_DIR", None)
+        _unset_fleet_env()
 
 
 def test_hb_handles_published_under_lock(tmp_path):
@@ -634,8 +644,7 @@ def test_hb_handles_published_under_lock(tmp_path):
         assert not t.is_alive() or t.join(5) is None
     finally:
         fleet._reset_for_tests()
-        os.environ.pop("LDDL_TPU_FLEET_DIR", None)
-        os.environ.pop("LDDL_TPU_FLEET_HEARTBEAT_S", None)
+        _unset_fleet_env()
 
 
 def test_backend_instances_lock_is_reentrant():

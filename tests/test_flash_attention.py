@@ -271,24 +271,127 @@ def _unpacked_keep(words, l):
     return np.concatenate(rows, axis=1).astype(bool)[:, :l, :l]
 
 
-@pytest.mark.parametrize("l,h", [(256, 4), (200, 4), (256, 2)],
-                         ids=["L256-nbh4", "L200-nbh4", "L256-nbh2"])
-def test_dropout_mask_is_bernoulli_bit_for_bit(l, h):
-    """The kernel draws exactly jax.random.bernoulli's [B, H, L, L] mask
-    for the same threefry key: aligned and unaligned L (L_pad 256), and
-    cells of 4 and 2 (batch, head) rows, whose global row index sets
-    each element's counter."""
+def _hashed_extent(mask, l, s):
+    """Bool [B, L, L]: the query rows and keys the forward hashes for each
+    batch row, one past its last valid key n: query slabs of S rows below
+    ceil(n / S), key blocks of 128 below ceil(n / 128)."""
+    mask = np.asarray(mask)
+    n = np.array([np.flatnonzero(row).max() + 1 if row.any() else 0
+                  for row in mask])
+    rows = -(-n // s) * s
+    cols = -(-n // 128) * 128
+    idx = np.arange(l)
+    return ((idx[None, :, None] < rows[:, None, None])
+            & (idx[None, None, :] < cols[:, None, None]))
+
+
+def _dropout_fwd(l, h, rows, seed=21):
+    """(q, k, v, mask, key, (out, lse, packed keep words)) of the kernel
+    forward with dropout 0.1: ``_inputs``' masks, every token valid, or
+    batch row 1 all padding."""
     from lddl_tpu.ops.flash_attention import (flash_attention_fwd,
                                               threefry_key_words)
-    b = 2
-    q, k, v, mask = _inputs(b=b, l=l, h=h, dtype=jnp.bfloat16, seed=21)
+    q, k, v, mask = _inputs(b=2, l=l, h=h, dtype=jnp.bfloat16, seed=seed)
+    if rows == "all-valid":
+        mask = jnp.ones_like(mask)
+    elif rows == "empty-row":
+        mask = mask.at[1].set(0)
     key = jax.random.fold_in(jax.random.PRNGKey(9), 3)
-    _, _, words = flash_attention_fwd(
-        q, k, v, mask, dropout_rate=0.1,
-        dropout_key=threefry_key_words(key))
+    res = flash_attention_fwd(q, k, v, mask, dropout_rate=0.1,
+                              dropout_key=threefry_key_words(key))
+    return q, k, v, mask, key, res
+
+
+@pytest.mark.parametrize("l,h,rows", [
+    (256, 4, "inputs"), (200, 4, "inputs"), (256, 2, "inputs"),
+    (256, 4, "all-valid"), (256, 4, "empty-row"), (512, 16, "inputs")],
+    ids=["L256-nbh4", "L200-nbh4", "L256-nbh2", "all-valid", "empty-row",
+         "L512-nbh4"])
+def test_dropout_mask_is_bernoulli_bit_for_bit(l, h, rows):
+    """Within each batch row's hashed extent the kernel draws exactly
+    jax.random.bernoulli's [B, H, L, L] mask for the same threefry key,
+    and stores zeros outside it: aligned and unaligned L, cells of 4 and 2
+    (batch, head) rows, whose global row index sets each element's
+    counter. ``_inputs``' row 0 is valid to 128 of L, so its key blocks
+    and query slabs beyond are skipped; with every token valid the stored
+    mask is the whole bernoulli tensor; an all-padding row stores none."""
+    b = 2
+    _, _, _, mask, key, (_, _, words) = _dropout_fwd(l, h, rows)
     keep = _unpacked_keep(words, l).reshape(b, h, l, l)
     want = np.asarray(jax.random.bernoulli(key, 0.9, (b, h, l, l)))
-    np.testing.assert_array_equal(keep, want)
+    inside = _hashed_extent(mask, l, words.shape[1])[:, None]
+    np.testing.assert_array_equal(keep, want & inside)
+    if rows == "all-valid":
+        np.testing.assert_array_equal(keep, want)
+    if rows == "empty-row":
+        assert not np.asarray(words).reshape(b, h, -1)[1].any()
+
+
+def _pack_keep(keep, l_pad, s):
+    """bool [B*H, L, L] -> the kernel's words [B*H, S, L_pad]: bit j of
+    word [q', k] is query row j*S + q'."""
+    n, l, _ = keep.shape
+    full = np.zeros((n, l_pad, l_pad), np.uint32)
+    full[:, :l, :l] = keep
+    words = np.zeros((n, s, l_pad), np.uint32)
+    for j in range(l_pad // s):
+        words |= full[:, j * s:(j + 1) * s] << np.uint32(j)
+    return jnp.asarray(words.view(np.int32))
+
+
+@pytest.mark.parametrize("rows", ["inputs", "empty-row"])
+def test_skipped_mask_backward_matches_full_mask(rows):
+    """The backward reading the forward's words, hashed within each row's
+    extent, gives dQ, dK, dV bit for bit those of the whole bernoulli mask
+    packed the same way, when padding rows carry a zero cotangent (as in
+    training): keys beyond the extent have an exact 0 probability, and
+    padding queries add nothing."""
+    from lddl_tpu.ops.flash_attention import flash_attention_bwd
+    b, l, h = 2, 256, 4
+    q, k, v, mask, key, (out, lse, words) = _dropout_fwd(l, h, rows)
+    full = _pack_keep(np.asarray(jax.random.bernoulli(
+        key, 0.9, (b, h, l, l))).reshape(b * h, l, l), l, words.shape[1])
+    g = np.random.default_rng(5)
+    ct = jnp.asarray(g.standard_normal(q.shape) * np.asarray(
+        mask)[:, :, None, None], jnp.bfloat16)
+    skipped = flash_attention_bwd(q, k, v, mask, out, lse, ct,
+                                  dropout_rate=0.1, keep=words)
+    whole = flash_attention_bwd(q, k, v, mask, out, lse, ct,
+                                dropout_rate=0.1, keep=full)
+    for a, w in zip(skipped, whole):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+@pytest.mark.parametrize("l,rows", [(256, "inputs"), (200, "inputs"),
+                                    (256, "empty-row"), (512, "inputs")])
+def test_dropout_mask_tiles_match_kernel(l, rows):
+    """``dropout_mask_tiles`` applies the kernel's extent rule: per batch
+    row, the tiles (S query rows by 128 keys) holding a kept bit of the
+    stored words are the tiles it counts as hashed, and the rest are the
+    ones it counts as skipped."""
+    from lddl_tpu.ops.flash_attention import dropout_mask_tiles
+    b, h = 2, 4
+    _, _, _, mask, _, (_, _, words) = _dropout_fwd(l, h, rows)
+    w = np.asarray(words).reshape(b, h, *words.shape[1:])
+    s, l_pad = w.shape[2:]
+    pack = l_pad // s
+    for i in range(b):
+        # [H, pack, S, L_pad] bits -> any set per (slab j, key block c).
+        bits = (w[i][:, None] >> np.arange(pack)[None, :, None, None]) & 1
+        tiles = bits.reshape(h, pack, s, l_pad // 128, 128).any(
+            axis=(2, 4))
+        hashed, skipped = dropout_mask_tiles(np.asarray(mask)[i:i + 1])
+        assert hashed + skipped == pack * (l_pad // 128)
+        for head in tiles:
+            assert int(head.sum()) == hashed, (i, hashed)
+            # The hashed tiles are the leading slabs by leading blocks.
+            rows_, cols = np.nonzero(head)
+            if hashed:
+                assert head[:rows_.max() + 1, :cols.max() + 1].all()
+    per_row = [dropout_mask_tiles(np.asarray(mask)[i:i + 1])
+               for i in range(b)]
+    assert dropout_mask_tiles(mask) == tuple(np.sum(per_row, axis=0))
 
 
 def _dense_dropout_attention(q, k, v, allowed, key, rate):
@@ -308,10 +411,12 @@ def test_dropout_matches_dense_dropout(packed):
     """Forward and dQ/dK/dV of the kernels with dropout against the dense
     path's Dropout-masked attention under the same key, at bf16 tolerance:
     a padding mask, and packed rows (the mask does not depend on
-    segments). The loss reads the real tokens' rows, as training's does:
-    a packed row's padding queries (segment 0) attend to no key, and the
+    segments). The output and the loss read the real tokens' rows, as
+    training's does: the kernel hashes no mask for query rows past a
+    row's last valid key, so their output is not dense's; and a packed
+    row's padding queries (segment 0) attend to no key, and the
     single-block backward's lse (~ -1e9) cannot hold their log-sum, so
-    their gradient is not dense's; their cotangent is zero in training."""
+    their gradient is not dense's. Their cotangent is zero in training."""
     b, l, h = 2, 256, 4
     q, k, v, mask = _inputs(b=b, l=l, h=h, dtype=jnp.bfloat16, seed=23)
     key = jax.random.PRNGKey(31)
@@ -326,7 +431,7 @@ def test_dropout_matches_dense_dropout(packed):
         kw = dict(segments=segs)
     else:
         allowed = mask[:, None, None, :] > 0
-        real = True
+        real = (mask > 0)[:, :, None, None]
         kw = dict(kv_mask=mask)
 
     def loss_f(q, k, v):
@@ -342,8 +447,9 @@ def test_dropout_matches_dense_dropout(packed):
         q, k, v)
     (_, ref), gd = jax.value_and_grad(loss_d, (0, 1, 2), has_aux=True)(
         q, k, v)
-    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
+    real = np.broadcast_to(real, out.shape)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                               np.asarray(ref)[real], rtol=2e-2, atol=2e-2)
     for a, r in zip(gf, gd):
         r = np.asarray(r)
         np.testing.assert_allclose(np.asarray(a, np.float32), r, rtol=2e-2,

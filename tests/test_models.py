@@ -273,9 +273,12 @@ def _selected(model, batch, seq_len, dropout_rng=None):
 def test_auto_flash_dropout_matches_dense(monkeypatch, tmp_path):
     """A tiny BERT at L=256 with dropout on: auto (flash, the kernels
     drawing the masks) against dense under the same params and dropout
-    rng, loss and gradients within bf16 tolerance. The trace-time counter
-    reads flash with dropout on every layer at L=256, dense at L=128, and
-    dense for an rbg key, whose mask the kernel cannot replay."""
+    rng, loss and gradients within bf16 tolerance. The loss reads the real
+    positions' MLM logits, as training's does: the kernels hash no mask
+    for padding rows, whose outputs differ from dense's. The trace-time
+    counter reads flash with dropout on every layer at L=256, dense at
+    L=128, and dense for an rbg key, whose mask the kernel cannot
+    replay."""
     import flax.linen as nn
 
     seq_len = 256
@@ -296,7 +299,9 @@ def test_auto_flash_dropout_matches_dense(monkeypatch, tmp_path):
             mlm, nsp = model.apply(
                 {"params": p}, batch["input_ids"], batch["token_type_ids"],
                 batch["attention_mask"], deterministic=False, rngs=rngs)
-            return (jnp.square(mlm.astype(jnp.float32)).mean()
+            real = batch["attention_mask"][:, :, None] > 0
+            return (jnp.where(real, jnp.square(mlm.astype(jnp.float32)),
+                              0.0).mean()
                     + jnp.square(nsp.astype(jnp.float32)).mean())
 
         results[impl] = jax.jit(jax.value_and_grad(loss))(params)

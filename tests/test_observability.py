@@ -469,6 +469,41 @@ def test_padding_efficiency_reproduces_bin_gap(corpus, binned_off,
             eff_binned, eff_unbinned))
 
 
+@pytest.mark.parametrize("armed", [True, False], ids=["armed", "off"])
+def test_dropout_mask_tiles_counted_once_per_batch(tmp_path, armed):
+    """``attention_dropout_mask_tiles_total`` counts each batch's hashed and
+    skipped tiles of the flash forward's dropout-mask hash once, from the
+    batch's attention mask, and only while telemetry is armed."""
+    from lddl_tpu.loader.dataloader import DataLoader
+    from lddl_tpu.ops.flash_attention import dropout_mask_tiles
+
+    lengths = [512, 100, 300, 0, 129, 384, 17]
+    masks = [(np.arange(512) < n).astype(np.int32) for n in lengths]
+
+    class Stream:
+        """One stream of [512] attention masks."""
+        def start_epoch(self):
+            return [iter(masks)]
+
+    if armed:
+        obs.configure(dir=str(tmp_path))
+    loader = DataLoader(Stream(), batch_size=3, collate_fn=lambda rows: {
+        "attention_mask": np.stack(rows)})
+    batches = [b for b in loader]
+    assert len(batches) == 3
+    values = obs.registry().counter(
+        "attention_dropout_mask_tiles_total").snapshot()["values"]
+    if not armed:
+        assert values == {}
+        return
+    want = [sum(x) for x in zip(*(dropout_mask_tiles(b["attention_mask"])
+                                  for b in batches))]
+    assert values == {"tile=hashed": want[0], "tile=skipped": want[1]}
+    # S = 16 query rows by 128 keys: 32 x 4 tiles a row.
+    assert sum(want) == len(lengths) * 32 * 4
+    assert want[0] == sum(-(-n // 16) * -(-n // 128) for n in lengths)
+
+
 # ------------------------------------------- disabled-mode cost guard
 
 
